@@ -38,6 +38,8 @@ def main(argv=None) -> int:
                     help="combined machine-readable summary (one row per "
                          "suite); empty string disables")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     steps = 30 if args.quick else 80
     # (suite name, entry module, runner taking the imported module) — modules

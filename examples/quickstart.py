@@ -280,9 +280,10 @@ def main():
     #     "<rep>@tpN" candidates (profile.ici_bytes_per_s prices the
     #     all-gather) and --path auto picks per stack. Below: the priced
     #     decision surface in-process, then the serve_tp DRYRUN as a
-    #     subprocess (it forces 512 simulated host devices via XLA_FLAGS
-    #     before importing jax, which this process — already running jax on
-    #     the real device set — must not do): it lowers sharded prefill +
+    #     subprocess pinned to the CPU (it forces 512 simulated host devices
+    #     via XLA_FLAGS before importing jax, which this process — already
+    #     running jax on the real device set — must not do; on the CPU it
+    #     never competes for the chip this process holds): it lowers sharded prefill +
     #     paged decode on a simulated 4-way model mesh and ASSERTS the SPMD
     #     invariants from the lowered HLO (per-stack isolated apply: 1
     #     all-gather, 0 stray collectives, shard-local (n/tp, k) gathers),
@@ -301,13 +302,15 @@ def main():
     cross = PLAN.tp_crossover_batch(stack11, itemsize=4, stats=stats11, tp=4)
     print(f"tp auto: predicted shard->replicate crossover batch: {cross} "
           f"(benchmarks/serve_paths.py records this per arch, schema v6)")
+    import os
     import subprocess
     import sys
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "qwen3-1.7b",
          "--shapes", "decode_32k", "--program", "serve_tp", "--tp", "4",
          "--smoke"],
-        capture_output=True, text=True)
+        capture_output=True, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
     for line in proc.stdout.splitlines():
         if "[serve_tp]" in line or "cells compiled" in line:
             print(f"dryrun| {line}")
@@ -326,10 +329,10 @@ def main():
     #     all-or-nothing at paged-chunk boundaries through the DONATED
     #     adoption path (no weight-memory doubling, no decode recompiles);
     #     stale/duplicate records drop, a gap triggers a full-snapshot
-    #     resync via the request-file back-channel. Below: publish in THIS
-    #     process while `serve.py --sync-dir` subscribes as a second
-    #     process — the production topology, two processes sharing only a
-    #     directory.
+    #     resync via the request-file back-channel. Below: publish, then
+    #     run `serve.py --sync-dir` in this same process as the subscriber
+    #     (in production they are two processes sharing only a directory;
+    #     here one process holds the device, and a second could not get it).
     import tempfile
     from repro.sync import DirChannel, Publisher
     sync_dir = tempfile.mkdtemp(prefix="repro-sync-")
@@ -352,17 +355,9 @@ def main():
                   f" ({info['bytes']} B: topo {info['topology_bytes']} + "
                   f"values {info['values_bytes']} + dense "
                   f"{info['dense_bytes']})")
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.launch.serve", "--arch", "qwen3-1.7b",
-         "--smoke", "--path", "condensed", "--batch", "2", "--prompt-len",
-         "8", "--gen", "8", "--sync-dir", sync_dir],
-        capture_output=True, text=True)
-    for line in proc.stdout.splitlines():
-        if "[serve" in line:
-            print(f"subscriber| {line}")
-    if proc.returncode:
-        print(proc.stdout[-2000:], proc.stderr[-2000:])
-        raise SystemExit("serve --sync-dir failed")
+    serve.main(["--arch", "qwen3-1.7b", "--smoke", "--path", "condensed",
+                "--batch", "2", "--prompt-len", "8", "--gen", "8",
+                "--sync-dir", sync_dir])
 
     # 13. self-draft speculative decoding: SRigL's neuron ablation means the
     #     served model already CONTAINS its own draft model — the SAME
@@ -410,18 +405,9 @@ def main():
           f"(lane padding hides tiny-dim savings; realistic d_out wins)")
     # the CLI drives the same thing: --speculative --gamma G
     # --draft-ablation F (a fixed --path forces; --path auto prices)
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.launch.serve", "--arch", "qwen3-1.7b",
-         "--smoke", "--path", "condensed", "--batch", "2", "--prompt-len",
-         "8", "--gen", "16", "--speculative", "--gamma", "3",
-         "--draft-ablation", "0.5"],
-        capture_output=True, text=True)
-    for line in proc.stdout.splitlines():
-        if "[serve:spec]" in line or "tok/s" in line:
-            print(f"spec-cli| {line}")
-    if proc.returncode:
-        print(proc.stdout[-2000:], proc.stderr[-2000:])
-        raise SystemExit("serve --speculative failed")
+    serve.main(["--arch", "qwen3-1.7b", "--smoke", "--path", "condensed",
+                "--batch", "2", "--prompt-len", "8", "--gen", "16",
+                "--speculative", "--gamma", "3", "--draft-ablation", "0.5"])
 
 
 if __name__ == "__main__":

@@ -22,7 +22,7 @@ shape + batch bucket (keys derive from ``formats.shape_tuning_key`` — the
 structured kernel's keys carry ``kind="structured"``), else the untimed
 VMEM-budget default inside the kernel module (which also routes B <= 8 to
 the decode-specialized variants). ``interpret`` resolves from the backend —
-interpret-mode only on CPU, overridable with REPRO_PALLAS_INTERPRET={0,1}.
+interpret mode on the CPU only.
 """
 from __future__ import annotations
 

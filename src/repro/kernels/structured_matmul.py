@@ -25,10 +25,10 @@ instead of a masked-out dense pass:
   int8/fp8 quantized storage, where no dense ``d_in x d_out`` weight exists
   to gather from). No gather pass appears in the program at all.
 * ``condensed_over_active_matmul`` — the combined Fig. 4 point, fused. The
-  condensed constant fan-in gather (same VMEM-local formulation as
-  ``condensed_matmul``) runs over the ``a <= d_out`` surviving rows and the
-  SAME one-hot epilogue scatters each row through ``out_index`` into the
-  dense output layout inside the kernel. This replaces the previous
+  condensed constant fan-in gather (``condensed_matmul._gather_reduce``,
+  the same VMEM-local lane-chunk gather) runs over the ``a <= d_out``
+  surviving rows and the SAME one-hot epilogue scatters each row through
+  ``out_index`` into the dense output layout inside the kernel. This replaces the previous
   compose-then-scatter lowering (``y.at[:, out_index].add``) that wrote the
   compact activations to HBM and re-read them in a separate scatter op —
   one full activation round trip per layer on the decode hot path. With
@@ -70,7 +70,9 @@ performs the column gather inside the kernel per compact tile — no XLA
 gather pass, no intermediate panel buffer. The price is full-weight VMEM
 residency (``d_in * d_out`` words), so it is gated on the VMEM budget and
 applies to decode shapes; enable via ``prefetch_gather=True`` or
-``REPRO_PREFETCH_GATHER=1``.
+``REPRO_PREFETCH_GATHER=1``. It runs in interpret mode only: Mosaic refuses
+the kernel for a TPU ("Can only load scalars from SMEM": the prefetched
+index vector is sliced as a vector), so it stays off the main path.
 
 VMEM budgets (words; ``d_in`` is structurally unblocked — the gather needs
 the whole activation row; ``d_out`` is unblocked only when ``block_o`` is
@@ -78,7 +80,7 @@ not used):
 
     structured: B_blk*d_in + d_in*N_blk + N_blk + B_blk*N_blk
                 + N_blk*O_blk + B_blk*O_blk          (O_blk = block_o or d_out)
-    coa fused:  B_blk*d_in + N_blk*k*2 + N_blk + B_blk*N_blk
+    coa fused:  B_blk*d_in + N_blk*k*3 + N_blk + B_blk*N_blk
                 + N_blk*O_blk + B_blk*O_blk
     prefetch:   B_pad*d_in + d_in*d_out + N_blk + B_pad*d_out + N_blk*d_out
 
@@ -107,11 +109,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific grid specs (scalar prefetch); present on CPU jaxlib too
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - exotic builds
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import condensed_matmul as cm
 
@@ -145,10 +143,11 @@ def structured_vmem_words(block_b: int, block_n: int, d_in: int,
 
 def coa_vmem_words(block_b: int, block_n: int, d_in: int, k: int,
                    d_out: int, block_o: int | None = None) -> int:
-    """x tile + (values + indices) tiles + out_index tile + compact-y tile +
-    one-hot tile + resident output block (``block_o`` tiles the last two)."""
+    """x tile + (values + indices) tiles + the values tile widened to f32 +
+    out_index tile + compact-y tile + one-hot tile + resident output block
+    (``block_o`` tiles the last two)."""
     o_blk = min(block_o or d_out, d_out)
-    return (block_b * d_in + block_n * k * 2 + block_n + block_b * block_n
+    return (block_b * d_in + block_n * k * 3 + block_n + block_b * block_n
             + block_n * o_blk + block_b * o_blk)
 
 
@@ -271,31 +270,37 @@ def _structured_prefetch_kernel(ai_ref, x_ref, w_ref, out_ref, *,
         out_ref[...] = out_ref[...] + contrib.astype(out_ref.dtype)
 
 
-def _coa_kernel(x_ref, w_ref, idx_ref, oi_ref, *rest, grid_axis: int,
-                scaled: bool = False, o_axis: int | None = None,
+def _coa_kernel(x_ref, wt_ref, it_ref, oi_ref, *rest, grid_axis: int,
+                d_out: int, round_to=None, scaled: bool = False,
+                widen: bool = False, o_axis: int | None = None,
                 block_o: int | None = None):
     """One surviving-row tile of the fused condensed-over-active matmul:
-    the condensed VMEM-local gather-reduce followed by the scatter epilogue.
+    the condensed gather-reduce (``condensed_matmul._gather_reduce``)
+    followed by the scatter epilogue.
 
-    x_ref  : (B_blk, d_in)  w_ref/idx_ref : (N_blk, k)  oi_ref : (1, N_blk)
+    x_ref  : (B_blk, d_pad) f32   wt_ref/it_ref : (k, N_blk), as stored
+    oi_ref : (1, N_blk)
     out_ref: (B_blk, d_out) resident across the row-tile axis ((B_blk,
     block_o) when out-blocked). ``scaled`` inserts a (1, N_blk) per-row f32
-    scale tile before the output ref: ``w_ref`` then holds int8/fp8 codes
+    scale tile before the output ref: ``wt_ref`` then holds int8/fp8 codes
     and the dequantize multiply fuses here, after the k-reduction (exact —
-    the scale is constant over a row's fan-in).
+    the scale is constant over a row's fan-in). ``widen`` appends the f32
+    scratch ``condensed_matmul._values_f32`` widens values into. Rows whose
+    ``out_index`` is the sentinel ``d_out`` (export padding, or the
+    unspecified rows of a tile that overhangs ``a``) are zeroed before the
+    one-hot matmul, which would otherwise spread a non-finite value over
+    every column.
     """
+    scratch = rest[-1:] if widen else ()
+    rest = rest[:-1] if widen else rest
     scale_ref = rest[0] if scaled else None
     out_ref = rest[-1]
+    wt = cm._values_f32(wt_ref, scratch, round_to)
     j = pl.program_id(grid_axis)
-    x = x_ref[...]
-    w = w_ref[...].astype(jnp.float32)
-    idx = idx_ref[...]
-    n_blk, k = idx.shape
-    gathered = jnp.take(x, idx.reshape(-1), axis=1).astype(jnp.float32)
-    gathered = gathered.reshape(x.shape[0], n_blk, k)
-    y = jnp.sum(gathered * w[None], axis=-1)             # (B_blk, N_blk) f32
+    y = cm._gather_reduce(x_ref, wt, it_ref)             # (B_blk, N_blk) f32
     if scaled:
-        y = y * scale_ref[...].astype(jnp.float32)       # (1, N_blk) bcast
+        y = y * scale_ref[...]                           # (1, N_blk) bcast
+    y = jnp.where(oi_ref[...] < d_out, y, 0.0)
     offset = 0 if o_axis is None else pl.program_id(o_axis) * block_o
     contrib = _onehot_scatter(y, oi_ref[...], out_ref.shape[-1], offset)
 
@@ -527,7 +532,7 @@ def structured_matmul_decode(
         _, block_n = default_structured_blocks(min(b, SMALL_BATCH_MAX), d_in,
                                                a, d_out)
     use_prefetch = prefetch_gather
-    if use_prefetch is None and pltpu is not None and block_o is None:
+    if use_prefetch is None and block_o is None:
         bp = _ceil_to(max(b, 1), SUBLANE)
         fits = (prefetch_vmem_words(bp, block_n, d_in, d_out) * cm._WORD
                 <= cm.vmem_budget_bytes())
@@ -591,124 +596,63 @@ def structured_matmul_pregathered(
 # ---------------------------------------------------------------------------
 
 
-def _coa_pad(values, indices, out_index, d_out: int, ap: int):
-    a = values.shape[0]
-    vp = jnp.pad(values, ((0, ap - a), (0, 0)))
-    ip = jnp.pad(indices.astype(jnp.int32), ((0, ap - a), (0, 0)))
-    oip = jnp.pad(out_index.astype(jnp.int32), (0, ap - a),
-                  constant_values=d_out).reshape(1, ap)
-    return vp, ip, oip
-
-
 @functools.partial(jax.jit, static_argnames=("d_out", "block_b", "block_n",
                                              "block_o", "interpret"))
 def _coa_tiled(x, values, indices, out_index, scales=None, *, d_out: int,
                block_b: int, block_n: int, block_o: int | None,
                interpret: bool):
-    b, d_in = x.shape
+    """Grid (batch tiles, surviving-row tiles), plus a d_out tile axis
+    between them when the epilogue is out-blocked."""
+    b = x.shape[0]
     a, k = values.shape
     bp, ap = _ceil_to(max(b, 1), block_b), _ceil_to(max(a, 1), block_n)
-    xp = jnp.pad(x, ((0, bp - b), (0, 0)))
-    vp, ip, oip = _coa_pad(values, indices, out_index, d_out, ap)
-
+    xp = cm.pad_x(x, bp)
+    # values/indices enter as stored, viewed (k, a) (a bitcast on a TPU;
+    # see condensed_matmul); only the (a,) row vectors are padded to whole
+    # tiles, out_index with the dropped sentinel
+    operands = [xp, values.T, indices.astype(jnp.int32).T,
+                jnp.pad(out_index.astype(jnp.int32), (0, ap - a),
+                        constant_values=d_out).reshape(1, ap)]
     scaled = scales is not None
-    operands = [xp, vp, ip, oip]
     if scaled:
         operands.append(jnp.pad(scales.astype(jnp.float32),
                                 (0, ap - a)).reshape(1, ap))
+    round_to = None if scaled else cm.round_dtype(x.dtype, values.dtype)
+    scratch = cm.values_scratch(values.dtype, round_to, k, block_n)
+    common = dict(d_out=d_out, scaled=scaled, round_to=round_to,
+                  widen=bool(scratch))
 
     if block_o is None:
-        in_specs = [
-            pl.BlockSpec((block_b, d_in), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
-        ]
-        if scaled:
-            in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, j)))
-        out = pl.pallas_call(
-            functools.partial(_coa_kernel, grid_axis=1, scaled=scaled),
-            grid=(bp // block_b, ap // block_n),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((block_b, d_out), lambda i, j: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((bp, d_out), x.dtype),
-            interpret=interpret,
-        )(*operands)
-        return out[:b]
+        grid = (bp // block_b, ap // block_n)
+        dop, o_blk = d_out, d_out
+        tile = lambda i, j: (i, j, 0)
+        kernel = functools.partial(_coa_kernel, grid_axis=1, **common)
+    else:
+        grid = (bp // block_b, _ceil_to(d_out, block_o) // block_o,
+                ap // block_n)
+        dop, o_blk = _ceil_to(d_out, block_o), block_o
+        tile = lambda i, o, j: (i, j, o)
+        kernel = functools.partial(_coa_kernel, grid_axis=2, o_axis=1,
+                                   block_o=block_o, **common)
 
-    dop = _ceil_to(d_out, block_o)
-    in_specs = [
-        pl.BlockSpec((block_b, d_in), lambda i, o, j: (i, 0)),
-        pl.BlockSpec((block_n, k), lambda i, o, j: (j, 0)),
-        pl.BlockSpec((block_n, k), lambda i, o, j: (j, 0)),
-        pl.BlockSpec((1, block_n), lambda i, o, j: (0, j)),
-    ]
+    def spec(shape, pick):
+        return pl.BlockSpec(shape, lambda *g: pick(*tile(*g)))
+
+    in_specs = [spec((block_b, xp.shape[1]), lambda i, j, o: (i, 0)),
+                spec((k, block_n), lambda i, j, o: (0, j)),
+                spec((k, block_n), lambda i, j, o: (0, j)),
+                spec((1, block_n), lambda i, j, o: (0, j))]
     if scaled:
-        in_specs.append(pl.BlockSpec((1, block_n), lambda i, o, j: (0, j)))
+        in_specs.append(spec((1, block_n), lambda i, j, o: (0, j)))
     out = pl.pallas_call(
-        functools.partial(_coa_kernel, grid_axis=2, scaled=scaled, o_axis=1,
-                          block_o=block_o),
-        grid=(bp // block_b, dop // block_o, ap // block_n),
+        kernel,
+        grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_b, block_o), lambda i, o, j: (i, o)),
+        out_specs=spec((block_b, o_blk), lambda i, j, o: (i, o)),
         out_shape=jax.ShapeDtypeStruct((bp, dop), x.dtype),
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(*operands)
-    return out[:b, :d_out]
-
-
-@functools.partial(jax.jit, static_argnames=("d_out", "block_n", "block_o",
-                                             "interpret"))
-def _coa_decode(x, values, indices, out_index, scales=None, *, d_out: int,
-                block_n: int, block_o: int | None, interpret: bool):
-    b, d_in = x.shape
-    a, k = values.shape
-    bp, ap = _ceil_to(max(b, 1), SUBLANE), _ceil_to(max(a, 1), block_n)
-    xp = jnp.pad(x, ((0, bp - b), (0, 0)))
-    vp, ip, oip = _coa_pad(values, indices, out_index, d_out, ap)
-
-    scaled = scales is not None
-    operands = [xp, vp, ip, oip]
-    if scaled:
-        operands.append(jnp.pad(scales.astype(jnp.float32),
-                                (0, ap - a)).reshape(1, ap))
-
-    if block_o is None:
-        in_specs = [
-            pl.BlockSpec((bp, d_in), lambda j: (0, 0)),
-            pl.BlockSpec((block_n, k), lambda j: (j, 0)),
-            pl.BlockSpec((block_n, k), lambda j: (j, 0)),
-            pl.BlockSpec((1, block_n), lambda j: (0, j)),
-        ]
-        if scaled:
-            in_specs.append(pl.BlockSpec((1, block_n), lambda j: (0, j)))
-        out = pl.pallas_call(
-            functools.partial(_coa_kernel, grid_axis=0, scaled=scaled),
-            grid=(ap // block_n,),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((bp, d_out), lambda j: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((bp, d_out), x.dtype),
-            interpret=interpret,
-        )(*operands)
-        return out[:b]
-
-    dop = _ceil_to(d_out, block_o)
-    in_specs = [
-        pl.BlockSpec((bp, d_in), lambda o, j: (0, 0)),
-        pl.BlockSpec((block_n, k), lambda o, j: (j, 0)),
-        pl.BlockSpec((block_n, k), lambda o, j: (j, 0)),
-        pl.BlockSpec((1, block_n), lambda o, j: (0, j)),
-    ]
-    if scaled:
-        in_specs.append(pl.BlockSpec((1, block_n), lambda o, j: (0, j)))
-    out = pl.pallas_call(
-        functools.partial(_coa_kernel, grid_axis=1, scaled=scaled, o_axis=0,
-                          block_o=block_o),
-        grid=(dop // block_o, ap // block_n),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bp, block_o), lambda o, j: (0, o)),
-        out_shape=jax.ShapeDtypeStruct((bp, dop), x.dtype),
-        interpret=interpret,
+        name="condensed_over_active_matmul",
     )(*operands)
     return out[:b, :d_out]
 
@@ -781,5 +725,6 @@ def condensed_over_active_matmul_decode(
     if block_n is None:
         _, block_n = default_coa_blocks(min(b, SMALL_BATCH_MAX), d_in, a, k,
                                         d_out)
-    return _coa_decode(x, values, indices, out_index, scales, d_out=d_out,
-                       block_n=block_n, block_o=block_o, interpret=interpret)
+    return _coa_tiled(x, values, indices, out_index, scales, d_out=d_out,
+                      block_b=_ceil_to(max(b, 1), SUBLANE), block_n=block_n,
+                      block_o=block_o, interpret=interpret)

@@ -1,11 +1,14 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above run before ANY other import (jax locks the device count
-on first init). For each cell this script:
+The lines above run before ANY other import (jax locks the platform and the
+device count on first init): the dry-run always runs on 512 forced HOST
+devices, never on an attached accelerator, so it can run beside a process
+that holds the chip. For each cell this script:
 
   1. builds the production mesh (16x16 single pod / 2x16x16 multi-pod),
   2. builds ShapeDtypeStruct stand-ins for the train/serve step inputs
@@ -31,10 +34,10 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat, configs
+from repro import configs
 from repro.data.pipeline import make_batch_spec
 from repro.launch import hlo_analysis as HLO
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.sharding import ShardingRules
 from repro.models import model as M
 from repro.sparse import registry as REG
@@ -81,7 +84,7 @@ def lower_train(cfg, shape, mesh):
                            microbatches=cfg.microbatches)
     jitted = jax.jit(step, in_shardings=(st_sh, b_sh),
                      out_shardings=(st_sh, None), donate_argnums=(0,))
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(state_sds, batch_sds)
 
 
@@ -103,7 +106,7 @@ def lower_dst(cfg, shape, mesh):
     step = make_dst_step(cfg, registry, compute_specs=None)
     jitted = jax.jit(step, in_shardings=(st_sh, b_sh), out_shardings=st_sh,
                      donate_argnums=(0,))
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(state_sds, batch_sds)
 
 
@@ -132,7 +135,7 @@ def lower_serve_planned(cfg, shape, mesh, reps: dict):
     jitted = jax.jit(serve_step,
                      in_shardings=(p_sh, m_sh, b_sh, c_sh),
                      out_shardings=(None, c_sh), donate_argnums=(3,))
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(params_sds, cond_sds, batch_sds, cache_sds)
 
 
@@ -186,8 +189,8 @@ def lower_serve_paged(cfg, shape, mesh):
     production scale. The dry-run pool holds exactly batch x pages-per-
     stream pages (batch-axis divisible); the engine's extra reserved
     garbage page rounds up to the next multiple in production."""
-    from repro.compat import NamedSharding
-    from repro.compat import PartitionSpec as P
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
     from repro.models import paged as PG
     if not M.supports_paged(cfg):
         raise ValueError(
@@ -228,7 +231,7 @@ def lower_serve_paged(cfg, shape, mesh):
     jitted = jax.jit(serve_step,
                      in_shardings=(p_sh, m_sh, b_sh, c_sh, t_sh, l_sh),
                      out_shardings=(None, c_sh), donate_argnums=(3,))
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(params_sds, masks_sds, batch_sds, pool_sds,
                             table_sds, len_sds)
 
@@ -241,8 +244,8 @@ def lower_zoo_engine(cfg, shape, mesh, reps: dict):
     masks slot, exactly what the engine's runners execute."""
     if not M.supports_paged(cfg):
         return lower_serve_planned(cfg, shape, mesh, reps)
-    from repro.compat import NamedSharding
-    from repro.compat import PartitionSpec as P
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
     from repro.models import paged as PG
     from repro.sparse import plan as PLAN
     rules = ShardingRules(cfg, mesh)
@@ -273,7 +276,7 @@ def lower_zoo_engine(cfg, shape, mesh, reps: dict):
     jitted = jax.jit(serve_step,
                      in_shardings=(p_sh, m_sh, b_sh, c_sh, t_sh, l_sh),
                      out_shardings=(None, c_sh), donate_argnums=(3,))
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(params_sds, cond_sds, batch_sds, pool_sds,
                             table_sds, len_sds)
 
@@ -361,22 +364,14 @@ def lower_serve(cfg, shape, mesh):
     jitted = jax.jit(serve_step,
                      in_shardings=(p_sh, m_sh, b_sh, c_sh),
                      out_shardings=(None, c_sh), donate_argnums=(3,))
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(params_sds, masks_sds, batch_sds, cache_sds)
 
 
 def tp_mesh(tp: int = 4):
     """Simulated (data=1, model=tp) mesh over the forced host devices — the
     smallest mesh that exercises the tensor-parallel serving path."""
-    return compat.make_mesh((1, int(tp)), ("data", "model"))
-
-
-def _gather_ok(shapes, nloc: int, k: int, d_out: int) -> bool:
-    """True iff some gather is shard-local ``(..., nloc, k)`` and none is the
-    replicated global ``(..., d_out, k)`` sparse gather."""
-    local = any(g[-2:] == (nloc, k) for g in shapes if len(g) >= 2)
-    global_ = any(g[-2:] == (d_out, k) for g in shapes if len(g) >= 2)
-    return local and (nloc == d_out or not global_)
+    return make_mesh((1, int(tp)), ("data", "model"))
 
 
 def run_tp_cell(arch: str, shape_name: str, tp: int = 4, quiet: bool = False,
@@ -388,8 +383,9 @@ def run_tp_cell(arch: str, shape_name: str, tp: int = 4, quiet: bool = False,
       1. per sparse stack (isolated apply program, condensed leaves in their
          tp-block layout): EXACTLY ONE all-gather — the output-partial
          collective the cost model prices — and no other collective;
-      2. every condensed gather in that program is shard-local: trailing
-         dims ``(d_out/tp, k)``, never the replicated ``(d_out, k)``;
+      2. every condensed gather in that program is shard-local: one of the
+         tp blocks of ``(d_out/tp, k)`` per device, never all of them nor
+         the unblocked ``(d_out, k)`` (``hlo_analysis.tp_gathers_shard_local``);
       3. the full prefill + paged-decode programs compile with the sharded
          serving tree, their gathers are shard-local for every divisible
          stack, and no global-shape sparse gather survives partitioning.
@@ -400,8 +396,8 @@ def run_tp_cell(arch: str, shape_name: str, tp: int = 4, quiet: bool = False,
     """
     import dataclasses as DC
 
-    from repro.compat import NamedSharding
-    from repro.compat import PartitionSpec as P
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
     from repro.core import distributions as D
     from repro.models import paged as PG
     from repro.sparse import formats as F
@@ -441,7 +437,7 @@ def run_tp_cell(arch: str, shape_name: str, tp: int = 4, quiet: bool = False,
                          in_shardings=(rules.masks(tree),
                                        NamedSharding(mesh, P())),
                          out_shardings=NamedSharding(mesh, P()))
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             hlo = jitted.lower(tree, x_sds).compile().as_text()
         pc = HLO.analyze(hlo)
         others = {c: n for c, n in pc.count_by_type.items()
@@ -452,9 +448,10 @@ def run_tp_cell(arch: str, shape_name: str, tp: int = 4, quiet: bool = False,
             f"{s.name}: expected exactly ONE all-gather for the sharded "
             f"apply, got {pc.count_by_type}")
         assert not others, f"{s.name}: unexpected collectives {others}"
-        assert _gather_ok(gshapes, nloc, k, s.d_out), (
+        assert HLO.tp_gathers_shard_local(gshapes, nloc, k, s.d_out), (
             f"{s.name}: gathers {gshapes} are not shard-local "
-            f"(want trailing ({nloc}, {k}), forbid ({s.d_out}, {k}))")
+            f"(want (1, {nloc}, {k}), forbid ({tp}, {nloc}, {k}) and "
+            f"({s.d_out}, {k}))")
         per_stack[s.name] = {
             "all_gather": 1, "gathers": [list(g) for g in gshapes],
             "nloc": nloc, "k": k,
@@ -474,7 +471,8 @@ def run_tp_cell(arch: str, shape_name: str, tp: int = 4, quiet: bool = False,
         gshapes = HLO.instruction_shapes(hlo, "gather")
         for s in tp_stacks:
             k = D.fan_in_from_density(s.d_in, s.density)
-            assert _gather_ok(gshapes, s.d_out // tp, k, s.d_out), (
+            assert HLO.tp_gathers_shard_local(gshapes, s.d_out // tp, k,
+                                              s.d_out), (
                 f"{name}/{s.name}: sparse gathers not shard-local in the "
                 f"full program: {sorted(set(gshapes))}")
         return HLO.analyze(hlo)
@@ -490,7 +488,7 @@ def run_tp_cell(arch: str, shape_name: str, tp: int = 4, quiet: bool = False,
     jitted = jax.jit(lambda p, c, b, kv: M.prefill_step(cfg, p, c, b, kv),
                      in_shardings=(p_sh, m_sh, b_sh, c_sh),
                      out_shardings=(None, c_sh), donate_argnums=(3,))
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         pre_hlo = jitted.lower(params_sds, cond_sds, pre_batch_sds,
                                cache_sds).compile().as_text()
     timings["prefill_s"] = round(time.time() - t0, 1)
@@ -515,7 +513,7 @@ def run_tp_cell(arch: str, shape_name: str, tp: int = 4, quiet: bool = False,
                 cfg, p, c, b, pool, tb, ln),
             in_shardings=(p_sh, m_sh, db_sh, pc_sh, t_sh, l_sh),
             out_shardings=(None, pc_sh), donate_argnums=(3,))
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             dec_hlo = jitted.lower(params_sds, cond_sds, dec_batch_sds,
                                    pool_sds, table_sds,
                                    len_sds).compile().as_text()
@@ -585,7 +583,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, quiet: bool = False,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     # trip-count-aware static cost model (xla's cost_analysis counts scan
     # bodies once — see hlo_analysis module docstring); bf16_equiv corrects

@@ -50,7 +50,9 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from repro.launch import sharding as SH
 from repro.launch import speculative as SP
 from repro.models import model as M
 from repro.models import paged as PG
@@ -708,8 +710,6 @@ class ServingEngine:
         if block_size < 1 or gen_chunk < 1:
             raise ValueError("block_size and gen_chunk must be >= 1")
         self.cfg = cfg
-        self.params = params
-        self.masks = masks or {}
         self.registry = list(REG.build_registry(cfg) if registry is None
                              else registry)
         self.path = path
@@ -726,6 +726,18 @@ class ServingEngine:
         self.mesh = mesh
         self.tp = (int(mesh.shape["model"])
                    if mesh is not None and "model" in mesh.axis_names else 1)
+        # on a device mesh (not a mesh-shaped stand-in that runs the block
+        # math on one device) activations stay replicated: params and masks
+        # live whole on every device, and only the plans' sharded leaves are
+        # split (placed in plan_for), so each sparse layer's one collective
+        # is the all-gather of its output blocks
+        self._place = self.tp > 1 and isinstance(mesh, Mesh)
+        if self._place:
+            replicated = NamedSharding(mesh, PartitionSpec())
+            params = jax.device_put(params, replicated)
+            masks = jax.device_put(masks, replicated) if masks else masks
+        self.params = params
+        self.masks = masks or {}
         self._mask_versions = mask_versions
         self._itemsize = jnp.dtype(cfg.param_dtype).itemsize
         self._stats: dict | None = None     # realized stats, computed once
@@ -787,6 +799,12 @@ class ServingEngine:
                 # rewrites stack leaves in EXISTING plans) — bring the
                 # fresh plan straight to the subscribed generation
                 self._apply_sync_to_plan(plan, self._subscriber, force=True)
+            if self._place and key.tp > 1:
+                # tp-block leaves split over the model axis, the rest
+                # replicated (launch.sharding's rules for format leaves)
+                rules = SH.ShardingRules(self.cfg, self.mesh)
+                plan.serving_tree = jax.device_put(
+                    plan.serving_tree, rules.masks(plan.serving_tree))
             self._plans[key] = plan
         return plan
 

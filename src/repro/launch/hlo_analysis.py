@@ -76,6 +76,9 @@ class Computation:
 _COMP_HEAD_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.+\{\s*$")
 _INST_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\([^()]*\)|[\w\[\],{}\s]+?)\s+"
                       r"([\w\-]+)\((.*)$")
+# a shape's layout, e.g. the TPU's ``f32[8]{0:T(128)S(1)}``: its tiling and
+# memory-space parens would end the type match early, and no analysis reads it
+_LAYOUT_RE = re.compile(r"\]\{[^{}]*\}")
 
 
 def parse_hlo(hlo_text: str) -> dict:
@@ -95,7 +98,7 @@ def parse_hlo(hlo_text: str) -> dict:
         if line.strip() == "}":
             cur = None
             continue
-        m = _INST_RE.match(line)
+        m = _INST_RE.match(_LAYOUT_RE.sub("]", line))
         if not m:
             continue
         name, type_str, op, rest = m.groups()
@@ -294,24 +297,98 @@ def analyze(hlo_text: str, bf16_equiv: bool = False) -> ProgramCost:
     return cost_of(entry, True)
 
 
+_OFFSET_DIMS_RE = re.compile(r"offset_dims=\{([\d,]*)\}")
+
+
+def _dims(type_str: str) -> tuple[int, ...] | None:
+    m = _SHAPE_RE.search(type_str)
+    return tuple(int(d) for d in m.group(2).split(",") if d) if m else None
+
+
+def _splits_batch_dims(dims, offset_dims, result) -> bool:
+    """True iff ``result`` is the gather shape ``dims`` with only its batch
+    dims split or merged: size-1 dims aside, every offset (slice) dim keeps
+    its size and place, and each run of batch dims becomes a run of result
+    dims with the same element count. A transposing bitcast (layouts are
+    stripped, so it prints like a reshape) moves a dim and fails."""
+    g = [(d, i not in offset_dims) for i, d in enumerate(dims) if d != 1]
+    r = [d for d in result if d != 1]
+    i = j = 0
+    while i < len(g):
+        if not g[i][1]:
+            if j >= len(r) or r[j] != g[i][0]:
+                return False
+            i, j = i + 1, j + 1
+            continue
+        run = 1
+        while i < len(g) and g[i][1]:
+            run *= g[i][0]
+            i += 1
+        got = 1
+        while got < run and j < len(r):
+            got *= r[j]
+            j += 1
+        if got != run:
+            return False
+    return j == len(r)
+
+
 def instruction_shapes(hlo_text: str, op: str = "gather") -> list[tuple[int, ...]]:
     """Result shapes (dim tuples) of every ``op`` instruction in the module,
     fusion bodies included. The tensor-parallel dry-run reads SPMD
-    invariants straight off the partitioned per-device HLO with this: a
-    shard-local condensed gather shows up as a ``gather`` whose trailing
-    dims are ``(n/tp, k)``, and a replicated one as ``(n, k)`` — the shapes
-    are the proof of where the partitioner actually split the work.
-    ``op`` matches the base opcode (async ``-start`` variants included)."""
+    invariants straight off the partitioned per-device HLO with this (see
+    ``tp_gathers_shard_local``): the shapes are the proof of where the
+    partitioner actually split the work. ``op`` matches the base opcode
+    (async ``-start`` variants included).
+
+    XLA may flatten a gather's batch dims into one and restore the requested
+    shape with a ``bitcast``/``reshape`` of its result (``f32[192,8,1]
+    gather`` with ``offset_dims={1,2}``, then ``f32[1,32,6,8] bitcast``).
+    Where such a consumer only splits or merges the gather's batch dims
+    (``_splits_batch_dims``), the restored shape is the one reported."""
     comps = parse_hlo(hlo_text)
     out: list[tuple[int, ...]] = []
     for c in comps.values():
+        reshapes: dict[str, list] = {}
+        for i in c.instructions:
+            if i.op in ("bitcast", "reshape") and i.operand_names:
+                reshapes.setdefault(i.operand_names[0], []).append(i)
         for i in c.instructions:
             if i.op != op and i.op != op + "-start":
                 continue
-            m = _SHAPE_RE.search(i.type_str)
+            dims = _dims(i.type_str)
+            if dims is None:
+                continue
+            m = _OFFSET_DIMS_RE.search(i.attrs) if op == "gather" else None
             if m:
-                out.append(tuple(int(d) for d in m.group(2).split(",") if d))
+                offsets = {int(d) for d in m.group(1).split(",") if d}
+                for u in reshapes.get(i.name, []):
+                    r = _dims(u.type_str)
+                    if r is not None and _splits_batch_dims(dims, offsets, r):
+                        dims = r
+                        break
+            out.append(dims)
     return out
+
+
+def tp_gathers_shard_local(shapes, nloc: int, k: int, d_out: int) -> bool:
+    """The serving SPMD invariant on a tensor-parallel program's gather
+    shapes (``instruction_shapes``): the condensed gather runs shard-local
+    and never replicated.
+
+    The TP apply vmaps the gather over the ``d_out / nloc`` neuron blocks,
+    so its restored shape reads ``(blocks, nloc, k, rows)``: ``blocks`` is 1
+    on each device when the partitioner split the block axis, and all of
+    them when it did not. True iff some gather holds ``(1, nloc, k)``, none
+    holds ``(blocks, nloc, k)``, and none the unblocked ``(d_out, k)``."""
+    def has(seq):
+        n = len(seq)
+        return any(tuple(g[i:i + n]) == seq
+                   for g in shapes for i in range(len(g) - n + 1))
+    if nloc == d_out:
+        return has((nloc, k))
+    return (has((1, nloc, k)) and not has((d_out // nloc, nloc, k))
+            and not has((d_out, k)))
 
 
 # backwards-compatible wrapper used by dryrun.py

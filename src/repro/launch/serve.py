@@ -65,6 +65,8 @@ import argparse
 import jax
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_serving_mesh
 from repro.launch.engine import (  # noqa: F401  (re-exported API surface)
     ServingEngine, _decode_loop, _prefill, generate, serve_once)
 from repro.models import model as M
@@ -167,6 +169,7 @@ def main(argv=None):
                          "serving (results persist in the autotune cache "
                          "and are picked up by the Pallas kernel wrappers)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config if args.smoke else configs.get_config)(args.arch)
     if not cfg.causal:
@@ -195,13 +198,11 @@ def main(argv=None):
               "value-storing formats (condensed/structured paths or auto)")
     mesh = None
     if args.tp > 1:
-        if jax.device_count() < args.tp:
-            raise SystemExit(
-                f"--tp {args.tp} needs {args.tp} devices, found "
-                f"{jax.device_count()} (simulated meshes live in "
-                "repro.launch.dryrun --program serve_tp)")
-        from repro import compat
-        mesh = compat.make_mesh((1, args.tp), ("data", "model"))
+        try:
+            mesh = make_serving_mesh(args.tp)
+        except ValueError as e:
+            raise SystemExit(f"--tp {args.tp}: {e} (simulated meshes live in "
+                             "repro.launch.dryrun --program serve_tp)")
         print(f"[serve] mesh data=1 model={args.tp}: sparse stacks shard "
               "the neuron axis where the cost model prices it a win")
     speculative = None

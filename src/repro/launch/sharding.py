@@ -27,7 +27,7 @@ hand-written per-arch table to drift out of sync.
 """
 from __future__ import annotations
 
-from repro.compat import NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.data.pipeline import Prefetcher, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.schedules import warmup_cosine
 from repro.train.trainer import Trainer
 
@@ -39,6 +40,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config if args.smoke else configs.get_config)(args.arch)
     sp = cfg.sparsity

@@ -25,7 +25,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as MOE
@@ -36,9 +35,9 @@ Masks = dict
 
 
 def _mesh_ok():
-    """Abstract mesh of the current trace, or None (via the compat shim —
-    jax.sharding.get_abstract_mesh only exists on newer JAX)."""
-    return compat.get_abstract_mesh()
+    """Abstract mesh of the current trace, or None outside ``jax.set_mesh``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def shard_hint(x: jax.Array, *spec):

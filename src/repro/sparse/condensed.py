@@ -85,7 +85,7 @@ def _condense_stack(weight, mask, k: int):
     """Condensed arrays at forced fan-in ``k`` (exactness-test reference)."""
     from repro.core import topology
     fn = lambda w, m: topology.dense_to_condensed(w, m, k)
-    vals, idx = F._vmap_lead(fn, weight.ndim - 2)(weight, mask)
+    vals, idx = F._map_lead(fn, weight.ndim - 2)(weight, mask)
     return {"values": vals, "indices": idx}
 
 

@@ -263,10 +263,16 @@ def _gather_rate(profile, batch: int) -> float:
     return profile.gather_flops_per_s
 
 
-def _vmap_lead(fn, n_lead: int):
-    for _ in range(n_lead):
+def _map_lead(fn, n_lead: int):
+    """``fn`` over ``n_lead`` leading (stack) axes: ``lax.map`` over the
+    first, one slab at a time, vmapped over the rest. An export sorts each
+    layer's (d_in, d_out) mask; vmapped over all of a full-width stack's
+    layers at once its temporaries alone outgrow one chip's HBM."""
+    if n_lead == 0:
+        return fn
+    for _ in range(n_lead - 1):
         fn = jax.vmap(fn)
-    return fn
+    return lambda *xs: jax.lax.map(lambda args: fn(*args), xs)
 
 
 def _realized_stats(mask) -> ExportStats:
@@ -301,7 +307,7 @@ def active_index_from_bools(neuron_active: jax.Array, a_pad: int) -> jax.Array:
         return jnp.pad(oi, (0, a_pad - n),
                        constant_values=d_out).astype(jnp.int32)
 
-    return _vmap_lead(fn, neuron_active.ndim - 1)(neuron_active)
+    return _map_lead(fn, neuron_active.ndim - 1)(neuron_active)
 
 
 def active_index_from_mask(mask: jax.Array, a_pad: int) -> jax.Array:
@@ -631,7 +637,7 @@ def _condense_active_stack(weight, mask, k: int, a: int, tp: int = 1):
         return vals, idx, jnp.where(sel, out_index, d_out).astype(jnp.int32)
 
     if tp <= 1:
-        return _vmap_lead(fn, weight.ndim - 2)(weight, mask)
+        return _map_lead(fn, weight.ndim - 2)(weight, mask)
 
     wloc = d_out // tp
 
@@ -655,14 +661,14 @@ def _condense_active_stack(weight, mask, k: int, a: int, tp: int = 1):
         return (vals.reshape(tp * a, k), idx.reshape(tp * a, k),
                 oi.reshape(tp * a))
 
-    return _vmap_lead(fn_tp, weight.ndim - 2)(weight, mask)
+    return _map_lead(fn_tp, weight.ndim - 2)(weight, mask)
 
 
 @functools.partial(jax.jit, static_argnames=("k",), donate_argnums=(2, 3),
                    keep_unused=True)
 def _recondense_donated(weight, mask, old_values, old_indices, *, k: int):
     fn = lambda w, m: topology.dense_to_condensed(w * m, m, k)
-    vals, idx = _vmap_lead(fn, weight.ndim - 2)(weight, mask)
+    vals, idx = _map_lead(fn, weight.ndim - 2)(weight, mask)
     return vals.astype(old_values.dtype), idx
 
 
@@ -683,8 +689,8 @@ def _gather_at_indices(weight, mask, indices, out_index=None):
 
     n_lead = weight.ndim - 2
     if out_index is None:
-        return _vmap_lead(fn, n_lead)(weight, mask, indices)
-    return _vmap_lead(fn, n_lead)(weight, mask, indices, out_index)
+        return _map_lead(fn, n_lead)(weight, mask, indices)
+    return _map_lead(fn, n_lead)(weight, mask, indices, out_index)
 
 
 @functools.partial(jax.jit, donate_argnums=(2,), keep_unused=True)
@@ -708,7 +714,7 @@ def _revalue_active_donated(weight, mask, old_values, indices, out_index):
 def _recondense_quantized_donated(weight, mask, old_values, old_indices,
                                   old_scales, *, k: int, qdt: str):
     fn = lambda w, m: topology.dense_to_condensed(w * m, m, k)
-    vals, idx = _vmap_lead(fn, weight.ndim - 2)(weight, mask)
+    vals, idx = _map_lead(fn, weight.ndim - 2)(weight, mask)
     q, s = quantize_values(vals, qdt)
     return q, idx, s
 
@@ -748,7 +754,7 @@ def _gather_active_panel(weight, mask, active_index):
         g = jnp.take(w * m, jnp.minimum(ai, d_out - 1), axis=1)
         return jnp.where((ai < d_out)[None, :], g, 0.0)
 
-    return _vmap_lead(fn, weight.ndim - 2)(weight, mask, active_index)
+    return _map_lead(fn, weight.ndim - 2)(weight, mask, active_index)
 
 
 @functools.partial(jax.jit, static_argnames=("qdt",), donate_argnums=(3, 4),
@@ -1128,15 +1134,11 @@ class Condensed(SparseFormat):
             # the plain condensed layout's contiguous neuron rows ARE the
             # shard blocks (constant fan-in: exactly balanced) — no array
             # reorganization, only the vmap-over-blocks execution
-            return ops.condensed_linear_tp_nd(
-                x, (self.values if self.scales is not None
-                    else self.values.astype(x.dtype)),
-                self.indices, self.tp, scales=self.scales)
-        if self.scales is not None:
-            return ops.condensed_linear_nd(x, self.values, self.indices,
-                                           scales=self.scales)
-        return ops.condensed_linear_nd(x, self.values.astype(x.dtype),
-                                       self.indices)
+            return ops.condensed_linear_tp_nd(x, self.values, self.indices,
+                                              self.tp, scales=self.scales)
+        # values pass as stored: the kernel rounds them to x's dtype in VMEM
+        return ops.condensed_linear_nd(x, self.values, self.indices,
+                                       scales=self.scales)
 
     @classmethod
     def export_from_dense(cls, w, mask, stats=None, *, quantize_spec=None,
@@ -1147,7 +1149,7 @@ class Condensed(SparseFormat):
         # rows already partition into equal blocks (validated divisible)
         tp = _check_tp_shards(int(w.shape[-1]), tp_shards)
         fn = lambda w_, m_: topology.dense_to_condensed(w_ * m_, m_, k)
-        vals, idx = _vmap_lead(fn, w.ndim - 2)(w, mask)
+        vals, idx = _map_lead(fn, w.ndim - 2)(w, mask)
         qdt = resolve_quantize_spec(quantize_spec)
         if qdt in QUANTIZED_DTYPES:
             q, s = quantize_values(vals, qdt)
@@ -1307,17 +1309,11 @@ class CondensedOverActive(SparseFormat):
             # out_index LOCALLY rebased (sentinel d_out // tp) — the local
             # scatter never crosses shards
             return ops.condensed_over_active_linear_tp_nd(
-                x, (self.values if self.scales is not None
-                    else self.values.astype(x.dtype)),
-                self.indices, self.out_index, self.d_out, self.tp,
-                scales=self.scales)
-        if self.scales is not None:
-            return ops.condensed_over_active_linear_nd(
                 x, self.values, self.indices, self.out_index, self.d_out,
-                scales=self.scales)
+                self.tp, scales=self.scales)
         return ops.condensed_over_active_linear_nd(
-            x, self.values.astype(x.dtype), self.indices, self.out_index,
-            self.d_out)
+            x, self.values, self.indices, self.out_index, self.d_out,
+            scales=self.scales)
 
     @classmethod
     def export_from_dense(cls, w, mask, stats=None, *, quantize_spec=None,

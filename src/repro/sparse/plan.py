@@ -218,8 +218,8 @@ class HardwareProfile:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as PS
 
-            from repro import compat
-            m = compat.make_mesh((ndev,), ("model",))
+            from repro.launch.mesh import make_mesh
+            m = make_mesh((ndev,), ("model",))
             per = max(int(stream_mb * 2**20 / 4) // ndev, 1024)
             xs_sh = jax.device_put(jnp.zeros((ndev * per,), jnp.float32),
                                    NamedSharding(m, PS("model")))

@@ -176,14 +176,18 @@ def test_dw_auto_blocks_stay_exact_and_grads_flow():
 
 
 def test_interpret_default_resolves_from_backend(monkeypatch):
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     assert cm.default_interpret("cpu") is True
     assert cm.default_interpret("tpu") is False
-    assert cm.default_interpret("gpu") is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert cm.default_interpret("cpu") is False
+    # the retired override must not force the interpreter onto a TPU
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert cm.default_interpret("tpu") is True
+    assert cm.default_interpret("tpu") is False
+
+
+def test_vmem_budget_unknown_backend_is_an_error(monkeypatch):
+    monkeypatch.delenv("REPRO_VMEM_CAP_BYTES", raising=False)
+    assert cm.vmem_budget_bytes("tpu") == cm.vmem_budget_bytes("cpu")
+    with pytest.raises(ValueError, match="no VMEM size known"):
+        cm.vmem_budget_bytes("gpu")
 
 
 # ---------------------------------------------------------------------------

@@ -1,48 +1,68 @@
-"""The JAX version-compat layer: shims behave identically on this install.
+"""The mesh APIs of the installed JAX, as the repo uses them.
 
-These tests are the contract the rest of the repo codes against — if a JAX
-upgrade changes mesh-context semantics, they fail here first, not deep inside
-a 512-device dry-run.
+``jax.set_mesh`` / ``jax.sharding.get_abstract_mesh`` and the Auto-axis
+meshes of ``launch.mesh`` are the contract the rest of the repo codes
+against — if a JAX upgrade changes mesh-context semantics, these fail here
+first, not deep inside a 512-device dry-run.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
+
+from repro.launch import serve
+from repro.launch.mesh import (make_host_mesh, make_mesh,
+                               make_production_mesh, make_serving_mesh)
 from repro.models import model as M
 
 
 def test_no_mesh_by_default():
-    assert compat.get_abstract_mesh() is None
+    assert M._mesh_ok() is None
 
 
 def test_use_mesh_exposes_abstract_mesh():
     mesh = make_host_mesh()
-    with compat.use_mesh(mesh):
-        am = compat.get_abstract_mesh()
+    with jax.set_mesh(mesh):
+        am = M._mesh_ok()
         assert am is not None
         assert tuple(am.axis_names) == ("data", "model")
         assert dict(am.shape) == {"data": 1, "model": 1}
-    assert compat.get_abstract_mesh() is None  # context restored
+    assert M._mesh_ok() is None  # context restored
 
 
 def test_use_mesh_nests_and_restores():
     mesh = make_host_mesh()
-    with compat.use_mesh(mesh):
-        with compat.use_mesh(mesh):
-            assert compat.get_abstract_mesh() is not None
-        assert compat.get_abstract_mesh() is not None
-    assert compat.get_abstract_mesh() is None
+    with jax.set_mesh(mesh):
+        with jax.set_mesh(mesh):
+            assert M._mesh_ok() is not None
+        assert M._mesh_ok() is not None
+    assert M._mesh_ok() is None
 
 
 def test_make_mesh_axis_names_and_usability():
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert tuple(mesh.axis_names) == ("data", "model")
-    sh = compat.NamedSharding(mesh, compat.PartitionSpec(None, None))
+    sh = NamedSharding(mesh, PartitionSpec(None, None))
     x = jax.device_put(jnp.ones((4, 4)), sh)
     assert float(x.sum()) == 16.0
+
+
+def test_serving_mesh_is_auto_and_checks_devices(monkeypatch):
+    """The tensor-parallel serving mesh (``serve.py --tp``) is Auto like
+    every other mesh; without enough devices it is refused, and the CLI
+    turns that into a readable exit."""
+    mesh = make_serving_mesh(1)
+    assert tuple(mesh.axis_names) == ("data", "model")
+    assert dict(mesh.shape) == {"data": 1, "model": 1}
+    assert set(mesh.axis_types) == {AxisType.Auto}
+    n = jax.device_count() + 1
+    with pytest.raises(ValueError, match=f"needs {n} devices"):
+        make_serving_mesh(n)
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    with pytest.raises(SystemExit, match=f"--tp {n}: a model axis"):
+        serve.main(["--arch", "qwen3-1.7b", "--smoke", "--tp", str(n)])
 
 
 def test_production_mesh_shape_via_compat():
@@ -64,12 +84,12 @@ def test_mesh_context_is_part_of_jit_trace():
 
     @jax.jit
     def f(x):
-        seen.append(compat.get_abstract_mesh() is not None)
+        seen.append(M._mesh_ok() is not None)
         return M.shard_hint(x, "data", None) * 2
 
     x = jnp.ones((2, 4))
     np.testing.assert_allclose(np.array(f(x)), 2.0)        # traced without mesh
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         np.testing.assert_allclose(np.array(f(x)), 2.0)    # must re-trace
     assert seen == [False, True]
 
@@ -82,20 +102,10 @@ def test_shard_hint_noop_without_mesh():
 
 def test_shard_hint_skips_indivisible_dims():
     mesh = make_host_mesh()
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         # 1x1 mesh: everything divides; constraint applies without error
         y = jax.jit(lambda x: M.shard_hint(x, "data", "model"))(jnp.ones((2, 2)))
         np.testing.assert_allclose(np.array(y), 1.0)
         # unknown axis name -> no-op rather than error
         z = jax.jit(lambda x: M.shard_hint(x, "nonexistent", None))(jnp.ones((2, 2)))
         np.testing.assert_allclose(np.array(z), 1.0)
-
-
-def test_format_shim_present():
-    """The layout shim resolves on every JAX that ships a layout module
-    (Format on current, Layout on 0.4.x) — serving code may pass
-    compat.default_format() anywhere a layout is accepted."""
-    if not compat.HAS_FORMAT:
-        pytest.skip("this JAX build has no jax.experimental.layout module")
-    assert compat.Format is not None
-    assert compat.default_format() is None

@@ -66,6 +66,50 @@ def test_shape_bytes():
     assert H._shape_bytes("pred[10]") == 10
 
 
+# trimmed from a program compiled for a v5e: TPU layouts carry tiling and
+# memory-space annotations in parens (``{0:T(128)S(1)}``)
+_TPU_HLO = """\
+ENTRY %main.1 (p0: bf16[8,512], p1: f32[8]) -> (bf16[8,2048], f32[8]) {
+  %p0 = bf16[8,512]{1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = f32[8]{0:T(128)} parameter(1)
+  %all-reduce.20 = f32[8]{0:T(128)S(1)} all-reduce(%p1), channel_id=5, replica_groups=[1,4]<=[4], to_apply=%add
+  %all-gather.21 = bf16[8,2048]{1,0:T(8,128)(2,1)S(1)} all-gather(%p0), channel_id=13, replica_groups=[1,4]<=[4], dimensions={1}
+  ROOT %tuple.1 = (bf16[8,2048]{1,0:T(8,128)(2,1)}, f32[8]{0:T(128)}) tuple(%all-gather.21, %all-reduce.20)
+}
+"""
+
+
+def test_tpu_layouts_parse():
+    cost = H.analyze(_TPU_HLO)
+    assert cost.count_by_type["all-gather"] == 1
+    assert cost.count_by_type["all-reduce"] == 1
+    assert H.instruction_shapes(_TPU_HLO, "all-gather") == [(8, 2048)]
+
+
+def _gather_hlo(gather_shape: str, offset_dims: str, user_shape: str) -> str:
+    return f"""\
+ENTRY %main.1 (p0: f32[8,64], p1: s32[192,1]) -> f32[1] {{
+  %p0 = f32[8,64]{{1,0}} parameter(0)
+  %p1 = s32[192,1]{{1,0}} parameter(1)
+  %gather.5 = f32[{gather_shape}]{{2,1,0}} gather(%p0, %p1), offset_dims={{{offset_dims}}}, collapsed_slice_dims={{}}, start_index_map={{1}}, index_vector_dim=1, slice_sizes={{8,1}}
+  ROOT %bitcast.6 = f32[{user_shape}]{{3,2,1,0}} bitcast(%gather.5)
+}}
+"""
+
+
+def test_gather_shape_restored_only_through_batch_dim_splits():
+    # XLA's form for a vmapped take: batch dim 192 = 32 x 6 flattened,
+    # offset dims (8, 1); the bitcast splits the batch dim back
+    hlo = _gather_hlo("192,8,1", "1,2", "1,32,6,8")
+    assert H.instruction_shapes(hlo, "gather") == [(1, 32, 6, 8)]
+    # a bitcast that moves the offset dim is a transpose, not a restore
+    hlo = _gather_hlo("192,8,1", "1,2", "8,32,6,1")
+    assert H.instruction_shapes(hlo, "gather") == [(192, 8, 1)]
+    # nor one that splits an offset dim
+    hlo = _gather_hlo("192,8,1", "1,2", "192,2,4")
+    assert H.instruction_shapes(hlo, "gather") == [(192, 8, 1)]
+
+
 def test_roofline_terms_and_dominance():
     t = H.roofline_terms(197e12, 819e9, 0.0, 1)
     assert t["compute_s"] == 1.0 and t["memory_s"] == 1.0

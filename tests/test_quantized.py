@@ -23,6 +23,7 @@ The PR's acceptance criteria made executable:
   byte width, and ``refresh`` preserves the precision.
 """
 import dataclasses
+import re
 import typing
 
 import jax
@@ -252,7 +253,7 @@ def _gather_count(hlo_text: str) -> int:
     metadata — the ONE XLA gather pass the decode scan hoists (see
     structured_matmul._gather_columns). The scalar-prefetch variant performs
     the gather in-kernel, so its program must not carry the tag at all."""
-    return hlo_text.count("hoisted_column_gather")
+    return len(re.findall(r'op_name="[^"]*hoisted_column_gather', hlo_text))
 
 
 def test_prefetch_gather_removes_hoisted_column_gather_from_hlo():

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _numerics import assert_sums_close, assert_zeros_where
 
 from repro.core import topology
 from repro.kernels import ops
@@ -63,6 +64,15 @@ STRUCT_SHAPES = [
 ]
 
 
+def _assert_matches_dense(y, y_ref, x, w, active):
+    """Ablated columns are exact zeros; surviving columns are the same
+    d_in-term dot as the reference, summed in the kernel's order (see
+    tests/_numerics.py for the bound)."""
+    assert_zeros_where(y, ~np.asarray(active)[None, :])
+    abs_sum = np.abs(np.asarray(x, np.float32)) @ np.abs(np.asarray(w, np.float32))
+    assert_sums_close(y, y_ref, abs_sum, x.shape[-1])
+
+
 @pytest.mark.parametrize("b,d_in,d_out,a", STRUCT_SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_structured_kernel_bit_identical_to_reference(b, d_in, d_out, a, dtype):
@@ -71,21 +81,22 @@ def test_structured_kernel_bit_identical_to_reference(b, d_in, d_out, a, dtype):
     y = sm.structured_matmul(x, w, ai)
     y_ref = ops.structured_dense(x, w, active)
     assert y.dtype == y_ref.dtype
-    np.testing.assert_array_equal(np.array(y), np.array(y_ref))
+    _assert_matches_dense(y, y_ref, x, w, active)
 
 
 def test_structured_kernel_forced_blocks_padding_paths():
     """Shapes straddling both block boundaries, blocks forced one-sided and
-    both-sided — all bit-identical to the reference."""
+    both-sided — ablated columns bitwise zero, the rest within the sum
+    reordering bound of the reference."""
     x, w, ai, active = _active_setup(130, 40, 200, 77, seed=11)
     y_ref = ops.structured_dense(x, w, active)
     for kw in ({"block_b": 32, "block_n": 128}, {"block_b": 128},
                {"block_n": 128}):
         y = sm.structured_matmul(x, w, ai, **kw)
-        np.testing.assert_array_equal(np.array(y), np.array(y_ref))
+        _assert_matches_dense(y, y_ref, x, w, active)
     # the decode variant agrees with the general kernel at any batch it fits
     y_dec = sm.structured_matmul_decode(x, w, ai)
-    np.testing.assert_array_equal(np.array(y_dec), np.array(y_ref))
+    _assert_matches_dense(y_dec, y_ref, x, w, active)
 
 
 def test_structured_linear_grads_match_reference():
