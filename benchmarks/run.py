@@ -10,7 +10,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   kernel_autotune — tuned-vs-default kernel blocks + calibrated crossover
   accuracy        — Tables 1-3 proxy: method ordering on a small LM
   gamma_sweep     — Fig. 8   gamma_sal sensitivity
-  roofline        — §Roofline aggregation of dry-run results (if present)
 
 Besides the CSV, the harness writes a combined ``BENCH_summary.json``
 (``--out``; empty string disables): ONE row per suite with its status,
@@ -59,7 +58,6 @@ def main(argv=None) -> int:
         ("accuracy", "accuracy", lambda m: m.run(steps=steps)),
         ("gamma_sweep", "gamma_sweep",
          lambda m: m.run(steps=min(steps, 60))),
-        ("roofline", "roofline", lambda m: m.run()),
     ]
 
     print("name,us_per_call,derived")

@@ -18,8 +18,6 @@ that holds the chip. For each cell this script:
      (FLOPs/bytes for §Roofline), and
   5. parses the partitioned HLO for collective traffic (hlo_analysis).
 
-Results are appended as JSON lines for benchmarks/roofline.py to aggregate.
-
 Usage:
   python -m repro.launch.dryrun --arch qwen3-1.7b --shape train_4k
   python -m repro.launch.dryrun --arch all [--shapes train_4k,prefill_32k]

@@ -62,14 +62,19 @@ def linear(x: jax.Array, w: jax.Array, mask=None) -> jax.Array:
       (``formats.from_legacy_leaf``); a dict with unrecognized keys raises a
       clear error instead of silently mis-dispatching.
     """
-    if isinstance(mask, dict):
-        # pre-formats serving trees: upgrade, then dispatch on type
-        mask = F.from_legacy_leaf(mask, d_in=w.shape[-2], d_out=w.shape[-1])
-    if isinstance(mask, F.SparseFormat):
-        return mask.apply(x, w)
-    if mask is not None:
+    if mask is None:
+        return x @ w.astype(x.dtype)
+    # one scope for every sparse stack, whatever its representation: a
+    # profile attributes their device time by it
+    with jax.named_scope("sparse"):
+        if isinstance(mask, dict):
+            # pre-formats serving trees: upgrade, then dispatch on type
+            mask = F.from_legacy_leaf(mask, d_in=w.shape[-2],
+                                      d_out=w.shape[-1])
+        if isinstance(mask, F.SparseFormat):
+            return mask.apply(x, w)
         w = apply_mask_for_forward(w, mask)
-    return x @ w.astype(x.dtype)
+        return x @ w.astype(x.dtype)
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:

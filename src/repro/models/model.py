@@ -277,6 +277,7 @@ def _heads(x, n, hd):
     return x.reshape(*x.shape[:-1], n, hd)
 
 
+@jax.named_scope("attention")
 def attn_sublayer(cfg, p: dict, m: dict, x: jax.Array, *,
                   positions, window: int, q_offset: int = 0,
                   cache: tuple | None = None, decode: bool = False,
@@ -431,7 +432,15 @@ def _maybe_remat(cfg, fn):
 def backbone(cfg, params: Params, masks: Masks, x: jax.Array, *,
              positions) -> tuple[jax.Array, jax.Array]:
     """Run the block stacks. x: (B, T, d). Returns (hidden, aux_loss)."""
-    masks = masks or {}
+    with jax.named_scope("blocks"):
+        x, aux_total = _scan_blocks(cfg, params, masks or {}, x, positions)
+    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return x, aux_total
+
+
+def _scan_blocks(cfg, params: Params, masks: Masks, x: jax.Array,
+                 positions) -> tuple[jax.Array, jax.Array]:
+    """The layer scans of each family. Returns (x, aux_loss)."""
     aux_total = jnp.zeros((), jnp.float32)
 
     if cfg.family in ("dense", "vlm", "audio", "vit") and not cfg.local_global_ratio:
@@ -531,8 +540,6 @@ def backbone(cfg, params: Params, masks: Masks, x: jax.Array, *,
                                 (params["m_rem"], _expand_masks(masks.get("m_rem", {}), None)))
     else:
         raise ValueError(cfg.family)
-
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux_total
 
 
@@ -545,6 +552,7 @@ def _expand_masks(mstack: dict, n_layers):
 # embedding / loss heads
 # ===========================================================================
 
+@jax.named_scope("embed")
 def embed_inputs(cfg, params: Params, batch: dict) -> tuple[jax.Array, Any]:
     """Token/frontend embedding. Returns (x (B,T,d), positions)."""
     dt = _dt(cfg)
@@ -634,25 +642,26 @@ def loss_fn(cfg, params: Params, masks: Masks, batch: dict) -> tuple[jax.Array, 
     x, positions = embed_inputs(cfg, params, batch)
     hidden, aux = backbone(cfg, params, masks, x, positions=positions)
 
-    if cfg.family == "vit":
-        pooled = jnp.mean(hidden, axis=1)
-        logits = (pooled @ params["lm_head"].astype(pooled.dtype)).astype(jnp.float32)
-        labels = batch["labels"]
-        loss = jnp.mean(jax.nn.logsumexp(logits, -1)
-                        - jnp.take_along_axis(logits, labels[:, None], -1)[:, 0])
-    elif cfg.family == "audio":
-        losses = [
-            cross_entropy_chunked(hidden, params["lm_head"][k],
-                                  batch["targets"][:, k], cfg.ce_chunk,
-                                  valid_vocab=cfg.vocab_size, cfg=cfg)
-            for k in range(cfg.n_codebooks)
-        ]
-        loss = sum(losses) / cfg.n_codebooks
-    else:
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        loss = cross_entropy_chunked(hidden, head, batch["targets"], cfg.ce_chunk,
-                                     batch.get("loss_mask"),
-                                     valid_vocab=cfg.vocab_size, cfg=cfg)
+    with jax.named_scope("head"):
+        if cfg.family == "vit":
+            pooled = jnp.mean(hidden, axis=1)
+            logits = (pooled @ params["lm_head"].astype(pooled.dtype)).astype(jnp.float32)
+            labels = batch["labels"]
+            loss = jnp.mean(jax.nn.logsumexp(logits, -1)
+                            - jnp.take_along_axis(logits, labels[:, None], -1)[:, 0])
+        elif cfg.family == "audio":
+            losses = [
+                cross_entropy_chunked(hidden, params["lm_head"][k],
+                                      batch["targets"][:, k], cfg.ce_chunk,
+                                      valid_vocab=cfg.vocab_size, cfg=cfg)
+                for k in range(cfg.n_codebooks)
+            ]
+            loss = sum(losses) / cfg.n_codebooks
+        else:
+            head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+            loss = cross_entropy_chunked(hidden, head, batch["targets"], cfg.ce_chunk,
+                                         batch.get("loss_mask"),
+                                         valid_vocab=cfg.vocab_size, cfg=cfg)
 
     total = loss + 0.01 * aux
     return total, {"loss": loss, "aux_loss": aux}

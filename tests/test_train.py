@@ -177,6 +177,21 @@ def test_checkpoint_restart_resumes_exactly():
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=ka)
 
 
+def test_fit_counts_each_host_sync():
+    """``fit`` turns a device value into a Python value once per call (the
+    step counter), once per step (is the DST update due?) and once per
+    logged step (the loss); each is counted where it happens."""
+    cfg = _cfg(delta_t=3)
+    tr = Trainer(cfg=cfg, lr_fn=lambda s: jnp.float32(1e-3), log_every=2)
+    state = init_train_state(cfg, jax.random.PRNGKey(0))
+    batches = _batches(cfg, 7)
+    quiet = lambda *_: None
+    state = tr.fit(state, iter(batches[:5]), 5, log_fn=quiet)
+    assert tr.host_syncs == 1 + 5 + 3               # steps 0, 2, 4 logged
+    tr.fit(state, iter(batches[5:]), 2, log_fn=quiet)
+    assert tr.host_syncs == 9 + 1 + 2 + 1           # step 6 logged
+
+
 @pytest.mark.parametrize("opt", ["sgdm", "adamw", "adafactor"])
 def test_optimizers_step(opt):
     init, update = make_optimizer(opt)
