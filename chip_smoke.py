@@ -411,6 +411,7 @@ def peak_memory(tag: str) -> None:
 def serve_phase(cfg, hbm: int, on_chip: bool):
     from repro.kernels import condensed_matmul as cm
     from repro.launch.engine import ServingEngine
+    from repro.models import attention as A
 
     cfg = serve_storage(cfg, hbm, copies=1)
     reg, params, masks = _init_weights(cfg)
@@ -446,6 +447,7 @@ def serve_phase(cfg, hbm: int, on_chip: bool):
     else:
         log("[serve:condensed] tpu_custom_call check: not on a TPU "
             "(interpreted kernels on the CPU)")
+    log(f"[serve] attention paths traced: {A.path_counts()}")
     m_eng, m_res, m_logits = out["masked"]
     compare_logits("serve:condensed-vs-masked", logits, m_logits)
     compare_tokens("serve:condensed-vs-masked", res, m_res, eng, m_eng)
@@ -458,6 +460,7 @@ def train_phase(cfg, hbm: int):
     import jax.numpy as jnp
     from repro.core import distributions as D
     from repro.data.pipeline import SyntheticLM
+    from repro.models import attention as A
     from repro.sparse import registry as REG
     from repro.train.trainer import Trainer
 
@@ -485,7 +488,10 @@ def train_phase(cfg, hbm: int):
     log(f"[train] {cfg.name}: {depth} layers at d_model {cfg.d_model}, batch "
         f"{batch_size} x {seq}, {steps} steps, DST every {sp.delta_t} "
         f"(step times below are cold: compile included)")
+    paths = A.path_counts()
     state = trainer.fit(state, batches, steps, log_fn=log_fn)
+    paths = {k: v - paths[k] for k, v in A.path_counts().items()}
+    log(f"[train] attention paths traced: {paths}")
     check(len(losses) == steps and all(math.isfinite(x) for x in losses),
           f"losses {losses}")
     versions = {k: int(v) for k, v in state.mask_versions.items()}
@@ -564,6 +570,7 @@ def aot_phase(cfg, hbm: int):
     from jax.sharding import SingleDeviceSharding
     from repro.kernels import condensed_matmul as cm
     from repro.launch import engine as E
+    from repro.models import attention as A
     from repro.models import model as M
     from repro.models import paged as PG
     from repro.sparse import plan as PLAN
@@ -573,8 +580,10 @@ def aot_phase(cfg, hbm: int):
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
     # this process runs on the CPU, where the kernels would pick interpret
-    # mode; the program compiled here is the chip's, so steer them
+    # mode and attention its chunked scan; the program compiled here is the
+    # chip's, so steer them
     cm.default_interpret = lambda backend=None: False
+    A._on_tpu = lambda: True
 
     def on_chip(tree):
         return jax.tree.map(

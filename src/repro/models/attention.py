@@ -18,13 +18,109 @@ Design points (see DESIGN.md §4):
   slab per q-chunk → O(T·window) compute, and use a **ring-buffer KV cache** of
   size ``window`` at decode time (gemma3's 5:1 local:global pattern makes the
   500k-context cell affordable: only the rare global layers keep full caches).
+* **Fused flash path**: on a TPU, causal full-context attention whose
+  sequence is a whole number of kernel blocks and whose head size fills the
+  lanes runs as one Pallas flash kernel (``flash_attention``, the shipped
+  splash kernel): the score tile stays in VMEM, tiles above the diagonal
+  are skipped, and the backward pass is the kernel's own dq/dkv kernels.
+  Everything else takes the chunked scan; ``path_counts`` says which ran.
 """
 from __future__ import annotations
 
+import collections
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as splash, splash_attention_mask as splash_mask)
 
 NEG_INF = jnp.float32(-1e30)
+
+# Trace-time count of the path each chunked_attention call took.
+_PATH_COUNTS = collections.Counter()
+
+# Flash q and kv block sizes, largest first; a sequence must divide by the
+# last. Scores are computed FLASH_KV_COMPUTE keys at a time within a block,
+# and a block's q or kv tile holds at most FLASH_TILE_BYTES (larger ones
+# overflow VMEM in the dq kernel on a v5e). Tuned on a v5e at T 2048, D 128
+# (benchmarks/attention_blocks.py).
+FLASH_BLOCKS = (1024, 512, 256, 128)
+FLASH_KV_COMPUTE = 512
+FLASH_TILE_BYTES = 512 * 1024
+
+
+def path_counts() -> dict[str, int]:
+    """How many ``chunked_attention`` calls traced so far took the fused
+    flash kernel (``flash``) and the chunked scan (``chunked``)."""
+    return {"flash": _PATH_COUNTS["flash"], "chunked": _PATH_COUNTS["chunked"]}
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _kv_group(h: int, head_to_kv: tuple) -> int:
+    """Q heads per kv head when ``head_to_kv`` groups them contiguously (q
+    head i reads kv head i // g), the kernel's own grouping; else 0."""
+    g = h // max(max(head_to_kv, default=0) + 1, 1)
+    return g if g and head_to_kv == tuple(i // g for i in range(h)) else 0
+
+
+def _one_device(x) -> bool:
+    """Whether ``x`` lives in a one-device program: XLA cannot partition a
+    Mosaic kernel over a mesh (tensor-parallel serving) by itself."""
+    mesh = jax.typeof(x).sharding.mesh
+    return mesh.empty or mesh.size == 1
+
+
+def flash_qualifies(q, k, *, head_to_kv, causal, window, q_offset) -> bool:
+    """Whether the fused flash kernel computes this call: on one TPU,
+    causal attention over the whole context, a sequence of whole kernel
+    blocks, lane-wide heads and contiguous GQA groups."""
+    _, tq, h, d = q.shape
+    return (_on_tpu() and causal and window == 0 and q_offset == 0
+            and tq == k.shape[1] and tq % FLASH_BLOCKS[-1] == 0
+            and d % 128 == 0 and _kv_group(h, head_to_kv) * k.shape[2] == h
+            and _one_device(q))
+
+
+def flash_block_sizes(t: int, d: int, itemsize: int) -> splash.BlockSizes:
+    """The kernel's tiles for a sequence of ``t`` and heads of ``d`` with
+    ``itemsize`` bytes per element: q and kv blocks of the largest of
+    ``FLASH_BLOCKS`` that divides ``t`` and keeps a tile within
+    ``FLASH_TILE_BYTES``, for every phase. The backward pass keeps separate
+    dq and dkv kernels: the fused one sums per-block dq partials rounded to
+    the input dtype."""
+    b = next(b for b in FLASH_BLOCKS if t % b == 0 and (
+        b * d * itemsize <= FLASH_TILE_BYTES or b == FLASH_BLOCKS[-1]))
+    c = min(b, FLASH_KV_COMPUTE)
+    return splash.BlockSizes(block_q=b, block_kv=b, block_kv_compute=c,
+                             block_q_dkv=b, block_kv_dkv=b,
+                             block_kv_dkv_compute=c, block_q_dq=b,
+                             block_kv_dq=b)
+
+
+def flash_attention(q, k, v, *, block_sizes: splash.BlockSizes | None = None,
+                    interpret: bool = False) -> jax.Array:
+    """Causal attention over the whole context as one Pallas flash kernel.
+
+    q: (B, T, H, D); k, v: (B, T, Hkv, D) with q head i reading kv head
+    i // (H / Hkv). Returns (B, T, H, D). q is pre-scaled by D ** -0.5 in
+    its own dtype, as ``chunked_attention`` does; scores and the softmax
+    statistics are f32. Differentiable: the backward pass runs the
+    kernel's dq and dkv kernels. ``block_sizes`` defaults to
+    ``flash_block_sizes``.
+    """
+    b, t, h, d = q.shape
+    mask = splash_mask.MultiHeadMask([splash_mask.CausalMask((t, t))] * h)
+    kernel = splash.make_splash_mha(
+        mask, block_sizes=block_sizes or flash_block_sizes(
+            t, d, q.dtype.itemsize), head_shards=1,
+        q_seq_shards=1, interpret=interpret)
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    out = jax.vmap(kernel)(heads_first(q * d ** -0.5), heads_first(k),
+                           heads_first(v))
+    return heads_first(out).astype(v.dtype)
 
 
 def expand_kv(k: jax.Array, head_to_kv: tuple) -> jax.Array:
@@ -54,7 +150,13 @@ def chunked_attention(
 
     q: (B, Tq, H, D); k, v: (B, S, Hkv, D). Returns (B, Tq, H, D).
     ``q_offset`` is the absolute position of q[0] (for prefill continuation).
+    Takes the fused flash kernel where ``flash_qualifies``.
     """
+    if flash_qualifies(q, k, head_to_kv=head_to_kv, causal=causal,
+                       window=window, q_offset=q_offset):
+        _PATH_COUNTS["flash"] += 1
+        return flash_attention(q, k, v)
+    _PATH_COUNTS["chunked"] += 1
     b, tq, h, d = q.shape
     s = k.shape[1]
     scale = d ** -0.5

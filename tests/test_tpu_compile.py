@@ -22,6 +22,7 @@ from repro import configs
 from repro.core import distributions as D
 from repro.kernels import condensed_matmul as cm
 from repro.kernels import structured_matmul as sm
+from repro.models import attention as A
 from repro.sparse import registry as REG
 
 # qwen3-1.7b's sparse stacks (configs/qwen3_1_7b.py) as (d_in, d_out, k):
@@ -30,6 +31,10 @@ STACKS = {s.name: (s.d_in, s.d_out, D.fan_in_from_density(s.d_in, s.density))
           for s in REG.build_registry(configs.get_config("qwen3-1.7b"))}
 D_MODEL, D_FF, K_UP = STACKS["blocks/w_up"]
 BATCHES = (8, 256)               # decode-specialized variant and tiled grid
+# the train-dst cell's attention as (batch, T, q heads, kv heads, head
+# size): 8 x 2048 tokens, 16 q heads over 8 kv heads of 128
+# (bench/configs/qwen3-1.7b-train-5l.json)
+ATTN_CELL = (8, 2048, 16, 8, 128)
 
 
 @pytest.fixture(scope="module")
@@ -151,3 +156,32 @@ def test_structured_compiles(chip, b):
         _spec(chip, (b, D_MODEL), jnp.bfloat16),
         _spec(chip, (D_MODEL, D_FF), jnp.bfloat16),
         _spec(chip, (a,), jnp.int32))
+
+
+@pytest.mark.parametrize("phase,shape,dtype", [
+    ("forward", ATTN_CELL, jnp.bfloat16),
+    ("grad", ATTN_CELL, jnp.bfloat16),
+    # f32 heads of 256: the largest tiles the block rule lets through
+    ("grad", (2, 2048, 8, 8, 256), jnp.float32)],
+    ids=["cell_forward", "cell_grad", "f32_d256_grad"])
+def test_flash_attention_compiles(chip, phase, shape, dtype):
+    b, t, h, hkv, d = shape
+
+    @jax.named_scope("attention")
+    def attend(q, k, v):
+        return A.flash_attention(q, k, v)
+
+    fn = attend if phase == "forward" else jax.grad(
+        lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2))
+    kv = _spec(chip, (b, t, hkv, d), dtype)
+    text = _compile_text(fn, _spec(chip, (b, t, h, d), dtype), kv, kv)
+    # the backward is the kernel's own dq and dkv kernels, and every kernel
+    # keeps the caller's scope in its op_name
+    kernels = re.findall(r'op_name="([^"]*/(splash_mha_\w+?))/pallas_call"',
+                         text)
+    want = ({"splash_mha_fwd_no_residuals"} if phase == "forward" else
+            {"splash_mha_fwd_residuals", "splash_mha_dq_no_residuals",
+             "splash_mha_dkv_no_residuals"})
+    assert {name for _, name in kernels} == want
+    assert all("attention" in path.split("/")[1] for path, _ in kernels)
