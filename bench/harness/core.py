@@ -1,7 +1,8 @@
 """What one run of a cell carries between its parts, and how the harness
 finds a cell's files by the names in ``BENCHMARK.json``:
 
-  configuration   the ``file`` its entry names (a JSON object)
+  configuration   the ``file`` its entry names (a JSON object), built and
+                  referenced through bench/harness/archs/<model_type>.py
   traffic mix     bench/traffic/<traffic>.json, run by the loop its
                   ``"loop"`` key names (bench/harness/<loop>.py)
   per-layer metric  bench/metrics/<name>.py, whose ``read(outcome)`` returns

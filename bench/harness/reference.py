@@ -1,9 +1,8 @@
-"""Plain reference of the dense Qwen3 block stack with SRigL masks.
+"""Plain reference of SRigL training, for any architecture module.
 
-Straightforward ``jax.numpy`` after the published Qwen3 description:
-pre-norm RMSNorm, grouped-query attention with per-head RMSNorm on q and k
-before rotary embedding (half-split rotation), causal softmax, SwiGLU MLP,
-tied embedding and head. Sparse linears multiply by ``weight * mask``. It
+Straightforward ``jax.numpy``: the forward pass and loss of one row are the
+architecture's (``harness.archs``, ``row_loss``), written after its
+published description; sparse linears multiply by ``weight * mask``. It
 imports nothing of the program; it reads the weights the benchmark made
 (``harness.weights``), where each norm's weight is 1 + the stored scale.
 
@@ -14,7 +13,7 @@ before the product, which is the step below bfloat16; gradients pass the
 rounding straight through.
 
 The SRigL topology update (Lasby et al., ICLR 2024, Sec. 3.1) is written
-out here as the paper states it, with exact sorts: per layer, the
+out here as the paper states it, with exact sorts: per matrix, the
 cosine-annealed share of active weights with the smallest magnitude is
 pruned; neurons with fewer salient weights than ``gamma_sal`` times the
 fan-in are ablated; each active neuron then refills to the constant fan-in
@@ -28,6 +27,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from harness import archs
 from harness import weights as W
 
 F8_MAX = 448.0
@@ -43,75 +43,22 @@ def _round_fp8(x, axis):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _mm(x, w, quant):
+def matmul(x, w, quant):
     """x (..., d_in) @ w (d_in, d_out) in float32."""
     if quant == "fp8":
         x, w = _round_fp8(x, -1), _round_fp8(w, 0)
     return x @ w
 
 
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-
-
-def _rope(x, pos, theta):
-    """x (T, H, D), pos (T,): rotate the two halves of each head."""
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos[:, None].astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def layer(model, lw, lm, h, quant=None):
-    """One block on one sequence. h (T, d) f32; lw/lm one layer's weights
-    and masks (any storage type)."""
+def masked(weights: dict, masks: dict) -> dict:
+    """One layer's weights in float32, each masked one as weight * mask with
+    the gradient of the unmasked weight passed straight through (the dense
+    gradient SRigL's grow step reads)."""
     f32 = lambda a: a.astype(jnp.float32)
-    eps = model["rms_norm_eps"]
-    nh, nkv, hd = (model["num_attention_heads"], model["num_key_value_heads"],
-                   model["head_dim"])
-    t = h.shape[0]
-    w = {k: f32(v) for k, v in lw.items()}
-    for name, m in lm.items():
-        # weight * mask, with the gradient of the unmasked weight passed
-        # straight through (the dense gradient SRigL's grow step reads)
+    w = {k: f32(v) for k, v in weights.items()}
+    for name, m in masks.items():
         w[name] = w[name] - jax.lax.stop_gradient(w[name] * (1.0 - f32(m)))
-    x = _rms(h, 1.0 + w["ln1"], eps)
-    q = _mm(x, w["wq"], quant).reshape(t, nh, hd)
-    k = _mm(x, w["wk"], quant).reshape(t, nkv, hd)
-    v = _mm(x, w["wv"], quant).reshape(t, nkv, hd)
-    q = _rms(q, 1.0 + w["q_norm"], eps)
-    k = _rms(k, 1.0 + w["k_norm"], eps)
-    pos = jnp.arange(t)
-    q, k = _rope(q, pos, model["rope_theta"]), _rope(k, pos, model["rope_theta"])
-    k = jnp.repeat(k, nh // nkv, axis=1)
-    v = jnp.repeat(v, nh // nkv, axis=1)
-    s = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(hd))
-    s = jnp.where(pos[None, :, None] >= pos[None, None, :], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("hqk,khd->qhd", p, v).reshape(t, nh * hd)
-    h = h + _mm(o, w["wo"], quant)
-    x = _rms(h, 1.0 + w["ln2"], eps)
-    g = _mm(x, w["w_gate"], quant)
-    u = _mm(x, w["w_up"], quant)
-    return h + _mm(jax.nn.silu(g) * u, w["w_down"], quant)
-
-
-def hidden(model, params, masks, tokens, quant=None, remat=False):
-    """Final-normed hidden states (T, d) of one token sequence."""
-    h = params["embed"][tokens].astype(jnp.float32)
-    body = functools.partial(layer, model, quant=quant)
-    if remat:
-        body = jax.checkpoint(body)
-
-    def step(h, xs):
-        lw, lm = xs
-        return body(lw, lm, h), None
-
-    h, _ = jax.lax.scan(step, h, (params["blocks"], masks["blocks"]))
-    return _rms(h, 1.0 + params["final_norm"].astype(jnp.float32),
-                model["rms_norm_eps"])
+    return w
 
 
 # ---------------------------------------------------------------------------
@@ -120,17 +67,16 @@ def hidden(model, params, masks, tokens, quant=None, remat=False):
 # ---------------------------------------------------------------------------
 
 
-def _row_loss(model, params, masks, tokens, targets, quant, chunk):
-    """Summed next-token loss of one row."""
-    hid = hidden(model, params, masks, tokens, quant, remat=True)
-    head_w = params["embed"].astype(jnp.float32).T
+def chunked_ce(hid, head_w, targets, quant, chunk):
+    """Summed next-token loss of one row's final hidden states (T, d) through
+    ``head_w`` (d, V), ``chunk`` positions at a time under remat."""
     chunk = min(chunk, hid.shape[0])
     n = hid.shape[0] // chunk
 
     @jax.checkpoint
     def body(tot, xs):
         h, t = xs
-        lg = _mm(h, head_w, quant)
+        lg = matmul(h, head_w, quant)
         gold = jnp.take_along_axis(lg, t[:, None], -1)[:, 0]
         return tot + jnp.sum(jax.nn.logsumexp(lg, -1) - gold), None
 
@@ -145,11 +91,12 @@ def loss_and_grads(model_items, params, masks, tokens, targets, quant, chunk):
     a time under remat, so one gradient tree and one row's activations are
     live at once."""
     model = W.unfreeze(model_items)
+    row_loss = archs.of(model).row_loss
 
     def total(p):
         @jax.checkpoint
         def one(tot, xs):
-            return tot + _row_loss(model, p, masks, *xs, quant, chunk), None
+            return tot + row_loss(model, p, masks, *xs, quant, chunk), None
 
         tot, _ = jax.lax.scan(one, jnp.float32(0), (tokens, targets))
         return tot / (tokens.shape[0] * tokens.shape[1])
@@ -190,20 +137,14 @@ def leaves(tree, prefix=()):
             yield "/".join(prefix + (k,)), v
 
 
-def _mask_of(masks, name):
-    parts = name.split("/")
-    node = masks
-    for p in parts:
+def at(tree, name):
+    """The leaf at a "/"-joined path, or None."""
+    node = tree
+    for p in name.split("/"):
         if not isinstance(node, dict) or p not in node:
             return None
         node = node[p]
     return node
-
-
-def _items(model):
-    return W.freeze(W.model_keys(model) | {
-        "rms_norm_eps": model["rms_norm_eps"],
-        "rope_theta": model["rope_theta"]})
 
 
 def train_steps(model, opt, params, masks, batches, lr, quant=None,
@@ -212,12 +153,12 @@ def train_steps(model, opt, params, masks, batches, lr, quant=None,
     ``params`` (float32, consumed). Returns (losses, first gradient norm per
     leaf as the optimizer takes it, parameters after the last step);
     ``observe(c, p)`` sees the flat parameters after each step c."""
-    items = _items(model)
+    items = W.freeze(model)
     hyper = tuple(float(x) for x in (opt["b1"], opt["b2"], opt["eps"],
                                      opt["weight_decay"], lr,
                                      opt["clip_norm"]))
     p = dict(leaves(params))
-    m = {k: v for k in p if (v := _mask_of(masks, k)) is not None}
+    m = {k: v for k in p if (v := at(masks, k)) is not None}
     mu = {k: jnp.zeros(v.shape, jnp.float32) for k, v in p.items()}
     nu = {k: jnp.zeros(v.shape, jnp.float32) for k, v in p.items()}
     losses, first = [], None
@@ -240,7 +181,7 @@ def dense_grads(model, params, masks, batch, quant=None, chunk=512):
     """The dense (straight-through) gradient of the mean loss of one batch
     at ``params`` (nested), the grow criterion of the topology update."""
     tokens, targets = batch
-    _, g = loss_and_grads(_items(model), params, masks, tokens, targets,
+    _, g = loss_and_grads(W.freeze(model), params, masks, tokens, targets,
                           quant, chunk)
     return g
 
@@ -296,30 +237,34 @@ def srigl_layer(w, g, mask, active, n_prune, k0: int, gamma_sal: float,
 
 
 def dst_masks(model, params, grads, masks, step: int, regrow_key=None):
-    """New masks ({stack: (layers, d_in, d_out) bool}) of the update at
-    ``step`` from float32 ``params`` and dense ``grads`` (nested trees).
+    """New masks of the update at ``step`` from float32 ``params`` and dense
+    ``grads`` (nested trees): a tree like ``masks``, each leaf (*lead, d_in,
+    d_out) bool, each index of its leading dims one matrix updated alone.
     ``regrow_key`` plants the fault of a regrow at random: the gradient's
     magnitudes are replaced by uniform noise."""
     sp = model["sparsity"]
     drop = drop_fraction(sp, step)
+    fan = W.fan_ins(model)
     out = {}
-    for name, m in masks["blocks"].items():
-        k0 = int(sp["fan_in"][name])
-        w, g = params["blocks"][name], grads["blocks"][name]
-        layers = []
+    for s, (name, m) in enumerate(leaves(masks)):
+        path = tuple(name.split("/"))
+        lead, (d_in, d_out) = m.shape[:-2], m.shape[-2:]
+        m = m.reshape(-1, d_in, d_out)
+        w = at(params, name).reshape(m.shape)
+        g = at(grads, name).reshape(m.shape)
+        new = []
         for i in range(m.shape[0]):
             gi = g[i].astype(jnp.float32)
             if regrow_key is not None:
                 gi = jax.random.uniform(
-                    jax.random.fold_in(regrow_key, len(out) * 1000 + i),
-                    gi.shape)
+                    jax.random.fold_in(regrow_key, s * 1000 + i), gi.shape)
             n_prune = math.floor(drop * int(jnp.sum(m[i])))
-            new, _ = srigl_layer(w[i].astype(jnp.float32), gi, m[i],
-                                 jnp.ones(m.shape[-1], bool), n_prune, k0,
-                                 float(sp["gamma_sal"]), bool(sp["ablation"]))
-            layers.append(new)
-        out[name] = jnp.stack(layers)
-    return out
+            new.append(srigl_layer(
+                w[i].astype(jnp.float32), gi, m[i], jnp.ones(d_out, bool),
+                n_prune, fan[path], float(sp["gamma_sal"]),
+                bool(sp["ablation"]))[0])
+        out[name] = jnp.stack(new).reshape(*lead, d_in, d_out)
+    return nest(out)
 
 
 def nest(flat: dict) -> dict:

@@ -1,16 +1,17 @@
 """Device time of the program's named scopes.
 
 The program names its layers with ``jax.named_scope``: ``sparse``,
-``attention``, ``blocks``, ``head`` and ``embed`` in the model,
-``optimizer`` in the train step, ``dst_grad``, ``dst_select`` and
-``dst_apply`` in the DST update. XLA keeps each name in the ``op_name``
-metadata of the instructions made from it, through differentiation
-(``transpose(jvp(blocks))/.../sparse/dot_general``) and remat
-(``.../checkpoint/rematted_computation/...``).
+``blocks``, ``head`` and ``embed`` in the model, ``optimizer`` in the train
+step, ``dst_grad``, ``dst_select`` and ``dst_apply`` in the DST update
+(``COMMON``), and each architecture its own sublayers (its module's
+``SCOPES`` in ``harness.archs``; qwen3's ``attention``). XLA keeps each
+name in the ``op_name`` metadata of the instructions made from it, through
+differentiation (``transpose(jvp(blocks))/.../sparse/dot_general``) and
+remat (``.../checkpoint/rematted_computation/...``).
 
 An operation of the trace is looked up by its instruction name in the
 compiled program whose execution (the "XLA Modules" line) holds it, and its
-device time goes to the innermost of that program's scopes (``SCOPES``)
+device time goes to the innermost of that program's scopes (``names``)
 its ``op_name`` names, or to ``other``: in the DST update the model's
 scopes sit inside ``dst_grad`` and count as it. Scopes do not overlap, so
 the scopes and ``other`` add up to the leaf operations' time in the
@@ -18,7 +19,8 @@ program's executions; the recompute (``rematted_computation``) is counted
 besides, across them.
 
 The compiled text is that of the train-step and DST programs built again
-from the cell's files, as the training loop builds them, and lowered at
+from the cell's files, as the training loop builds them (through the
+configuration's architecture module), and lowered at
 their shapes; with the persistent compilation cache on, compiling them
 again loads the executable that ran. Time of an operation the text lacks
 is reported (``unmatched``). A program without the scopes gives nothing.
@@ -32,21 +34,51 @@ import re
 from harness import trace as TR
 from harness.core import log
 
-# program (found in module names) -> its scopes
-SCOPES = {"train_step": ("sparse", "attention", "blocks", "head", "embed",
-                         "optimizer"),
+# program (found in module names) -> the scopes every architecture has
+COMMON = {"train_step": ("sparse", "blocks", "head", "embed", "optimizer"),
           "dst_step": ("dst_grad", "dst_select", "dst_apply")}
 OTHER = "other"
 REMAT = "rematted_computation"
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+) .*\{$")
-_INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = ')
-_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_INSTR = re.compile(r'^\s+(?:ROOT\s+)?%?([^\s=]+) = ')
+_OP_NAME = re.compile(r'(?<!\w)metadata=\{[^}]*?op_name="([^"]*)"')
 _OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
 _CALLS = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
 _CALL_SETS = re.compile(r"(?:branch|called)_computations=\{([^}]*)\}")
 _NAME = re.compile(r"%([\w.\-]+)")
 _WRAPPED = re.compile(r"^[\w-]+\((.*)\)$")
+
+
+def names(model: dict) -> dict[str, tuple[str, ...]]:
+    """Program -> its scopes, for a configuration's architecture."""
+    from harness import archs
+    own = archs.of(model).SCOPES
+    return {p: s + own if p == "train_step" else s
+            for p, s in COMMON.items()}
+
+
+def instructions(hlo_text: str):
+    """(computation, instruction name, the text after its " = ") of each
+    instruction, with the lines it continues on: a Pallas call prints its
+    ``kernel_metadata`` over three lines and its ``op_name`` on the third.
+    A line that opens no computation or instruction and closes no
+    computation continues the instruction before it."""
+    comp, inst, rest = None, None, []
+    for line in hlo_text.splitlines():
+        c = _COMPUTATION.match(line)
+        m = None if c else _INSTR.match(line)
+        if inst is not None and (c or m or line.strip() in ("", "}")):
+            yield comp, inst, "\n".join(rest)
+            inst = None
+        if c:
+            comp = c.group(1)
+        elif m:
+            inst, rest = m.group(1), [line[m.end():]]
+        elif inst is not None:
+            rest.append(line)
+    if inst is not None:
+        yield comp, inst, "\n".join(rest)
 
 
 def _opcode_and_operands(rest: str) -> tuple[str, list[str]]:
@@ -73,17 +105,8 @@ def op_paths(hlo_text: str) -> dict[str, tuple[str, str]]:
     (``operand``), else that of the loop or call whose body holds it
     (``loop``); ("", ``none``) where none is found."""
     own, operands, fused, comp_of, caller = {}, {}, {}, {}, {}
-    in_comp: dict[str, list[str]] = {}
-    comp = None
-    for line in hlo_text.splitlines():
-        if (c := _COMPUTATION.match(line)):
-            comp = c.group(1)
-            in_comp[comp] = []
-            continue
-        m = _INSTR.match(line)
-        if not m:
-            continue
-        inst, rest = m.group(1), line[m.end():]
+    in_comp: dict[str, list[str]] = collections.defaultdict(list)
+    for comp, inst, rest in instructions(hlo_text):
         name = _OP_NAME.search(rest)
         own[inst] = name.group(1) if name else ""
         opcode, operands[inst] = _opcode_and_operands(rest)
@@ -101,9 +124,9 @@ def op_paths(hlo_text: str) -> dict[str, tuple[str, str]]:
     def named(inst):
         if own.get(inst) or inst not in fused:
             return own.get(inst, ""), "own"
-        names = in_comp.get(fused[inst])
-        return (collections.Counter(names).most_common(1)[0][0]
-                if names else ""), "fused"
+        inner = in_comp.get(fused[inst])
+        return (collections.Counter(inner).most_common(1)[0][0]
+                if inner else ""), "fused"
 
     out: dict[str, tuple[str, str]] = {}
 
@@ -151,8 +174,9 @@ def scope_of(path: str, scopes) -> str:
 
 
 def attribute(events, modules, lo, hi,
-              paths: dict[str, dict[str, tuple[str, str]]]):
-    """Per program (a key of ``paths`` and ``SCOPES``): its
+              paths: dict[str, dict[str, tuple[str, str]]],
+              scopes: dict[str, tuple[str, ...]]):
+    """Per program (a key of ``paths`` and ``scopes``): its
     executions inside [lo, hi), their seconds, and the seconds of the leaf
     operations they hold by scope, with ``other``, the recompute
     (``remat``, across the scopes), the time of instructions the
@@ -182,7 +206,7 @@ def attribute(events, modules, lo, hi,
             continue
         r, dt = out[p], (e - s) / 1e9
         path, rule = paths[p].get(name, (None, "unmatched"))
-        scope = OTHER if path is None else scope_of(path, SCOPES[p])
+        scope = OTHER if path is None else scope_of(path, scopes[p])
         r["seconds"][scope] = r["seconds"].get(scope, 0.0) + dt
         by_rule = r["rules"].setdefault(scope, {})
         by_rule[rule] = by_rule.get(rule, 0.0) + dt
@@ -226,7 +250,7 @@ def compiled_texts(model: dict, traffic: dict) -> dict[str, str]:
     batch = jax.eval_shape(lambda: train._batch(
         jax.random.PRNGKey(0), 0, rows, seq, model["vocab_size"]))
     return {name: fn.lower(state, batch).compile().as_text()
-            for name, fn in zip(SCOPES, trainer.programs()) if fn}
+            for name, fn in zip(COMMON, trainer.programs()) if fn}
 
 
 def reading(out) -> dict | None:
@@ -242,7 +266,8 @@ def reading(out) -> dict | None:
         texts = compiled_texts(out.model, out.traffic)
         if texts:
             got = attribute(t.devices[0], t.modules[0], t.lo, t.hi,
-                            {p: op_paths(x) for p, x in texts.items()})
+                            {p: op_paths(x) for p, x in texts.items()},
+                            names(out.model))
             _log(got)
     out.counters["scopes"] = got
     return got

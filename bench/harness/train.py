@@ -14,8 +14,10 @@ same place, and the masks it made are kept for the check.
 After the window the plain reference (``harness.reference``) runs the same
 steps from the same weights, the fourth included, recomputes the dense
 gradient there and makes its own topology update. The gaps are compared
-leaf by leaf, and the update layer by layer, with the traffic file's
-limits. Fan-in must be constant on every active neuron after the window.
+leaf by leaf, and the update matrix by matrix (each index of a stack's
+leading dims is one), with the traffic file's limits. Fan-in must be
+constant on every active neuron after the window. The ``Trainer``'s
+counters over the window are handed on as ``program.<name>``.
 
 With ``--control`` or ``--fault`` the program is not run: the reference put
 in its place (in fp8, on half of each batch, or regrowing at random) gives
@@ -30,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import core, cost, program, reference
+from harness import archs, core, program, reference
 from harness import trace as TR
 from harness import weights as W
 from harness.core import Check, Outcome, log, now
@@ -78,10 +80,12 @@ def _change_norms(p_new, p_old) -> dict[str, float]:
 
 def _state(cfg, reg, params, masks, step0, key):
     from repro.optim import make_optimizer
+    from repro.sparse import registry as REG
     from repro.train.state import TrainState
     opt_init, _ = make_optimizer(cfg.optimizer)
-    active = {"blocks": {s.path[-1]: jnp.ones((*s.lead, s.d_out), bool)
-                         for s in reg}}
+    active = {}
+    for s in reg:
+        REG.set_path(active, s.path, jnp.ones((*s.lead, s.d_out), bool))
     return TrainState(
         step=jnp.asarray(step0, jnp.int32), params=params,
         opt_state=opt_init(params), masks=masks, neuron_active=active,
@@ -90,17 +94,17 @@ def _state(cfg, reg, params, masks, step0, key):
         rng=key)
 
 
-def _fan_in_faults(model, reg, state) -> int:
-    """Stacks in which an active neuron's fan-in is not the configuration's
-    k, or an ablated neuron keeps inputs."""
-    from repro.sparse import registry as REG
+def fan_in_faults(model, masks, active) -> int:
+    """Sparse matrices (each index of a stack's leading dims is one) in
+    which an active neuron's fan-in is not the configuration's k, or an
+    ablated neuron keeps inputs."""
     bad = 0
-    for s in reg:
-        k = int(model["sparsity"]["fan_in"][s.path[-1]])
-        nnz = np.asarray(jnp.sum(REG.get_path(state.masks, s.path), axis=-2))
-        active = np.asarray(REG.get_path(state.neuron_active, s.path))
-        ok = np.all(nnz[active] == k) and np.all(nnz[~active] == 0)
-        bad += not ok
+    for path, k in W.fan_ins(model).items():
+        name = "/".join(path)
+        nnz = np.asarray(jnp.sum(reference.at(masks, name), axis=-2))
+        on = np.asarray(reference.at(active, name))
+        ok = np.where(on, nnz == k, nnz == 0).all(axis=-1)
+        bad += int(np.sum(~ok))
     return bad
 
 
@@ -114,10 +118,11 @@ def run(r: core.Run) -> Outcome:
     step0 = delta_t - 1 - CHECKED_STEPS
     opt = model["optimizer"]
     lr = float(opt["lr"])
+    arch = archs.of(model)
     cfg = program.arch_config(model, dtype=model["compute_dtype"],
                               param_dtype=model["param_dtype"])
     params, masks = W.make(model, model["param_dtype"], r.seed)
-    reg = program.check_layout(cfg, model, params)
+    reg = program.check_layout(cfg, model, params, masks)
     state = _state(cfg, reg, params, masks, step0,
                    jax.random.fold_in(W.key_from_seed(r.seed), 3))
     del params, masks
@@ -146,6 +151,8 @@ def run(r: core.Run) -> Outcome:
     counter = core.CompileCounter()
     tracer = TR.Tracer(r.trace)
     quiet = lambda msg: None
+    counted = program.COUNTERS + arch.COUNTERS
+    before = program.counters(trainer, counted)
 
     setup_s = now() - r.t_start
     counter.active = True
@@ -176,12 +183,16 @@ def run(r: core.Run) -> Outcome:
     log(f"[train] window {window:.3f} s: {steps} steps of {rows} x {seq} "
         f"tokens, {dst} DST update(s) in the window, programs compiled in "
         f"the window: {counter.count}")
-    fan_faults = _fan_in_faults(model, reg, state)
+    fan_faults = fan_in_faults(model, state.masks, state.neuron_active)
     if updated is None:             # no update ran: the masks as they stand
         updated = state.masks
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
-    counters = {"steps": steps, "dst_updates": dst, "window_s": window,
-                "flops": steps * cost.train_flops_per_step(model, rows, seq)}
+    # the program's counters over the window
+    counters = {k: v - before[k] for k, v in
+                program.counters(trainer, counted).items()}
+    counters |= {"steps": steps, "dst_updates": dst, "window_s": window}
+    counters["flops"] = steps * arch.flops_per_step(model, rows, seq,
+                                                    counters)
     del state, trainer
     gc.collect()
     live = sum(a.nbytes for a in jax.live_arrays())
@@ -190,8 +201,8 @@ def run(r: core.Run) -> Outcome:
         f"in use {use}")
 
     ref = reference_readings(model, opt, r.seed, rows, seq, None)
-    checks = _checks(model, tr, (losses, first, change, updated["blocks"]),
-                     ref, r.seed)
+    checks = _checks(model, tr, (losses, first, change, updated), ref,
+                     r.seed)
     checks.append(Check("fan_in_faults", float(fan_faults), 0.0))
     return Outcome(metrics=metrics, checks=checks, attempted=steps,
                    failed=0, counters=counters, trace=tracer.reduce(),
@@ -237,24 +248,24 @@ def gaps(prog_losses, prog_first, prog_change, ref):
 
 @jax.jit
 def _update_counts(old, new, ref):
-    """Per layer: connections the update dropped, those the reference
+    """Per matrix: connections the update dropped, those the reference
     dropped, and those on which the two new masks disagree."""
-    ax = (1, 2)
+    ax = (-2, -1)
     return (jnp.sum(old & ~new, axis=ax), jnp.sum(old & ~ref, axis=ax),
             jnp.sum(new ^ ref, axis=ax))
 
 
 def update_gaps(old: dict, new: dict, ref: dict) -> tuple[float, float]:
-    """(flip gap, mask mismatch), each the worst layer of any stack: the
-    gap between the counts of connections dropped, over the reference's
-    count; and the connections on which the new masks disagree, over twice
-    the reference's count (1 where the update left the masks as they
-    were)."""
+    """(flip gap, mismatch), each the worst matrix of any mask (each index
+    of a stack's leading dims is one): the gap between the counts of
+    connections dropped, over the reference's count; and the connections
+    on which the new masks disagree, over twice the reference's count (1
+    where the update left the masks as they were)."""
     flip = mismatch = 0.0
-    for name in ref:
+    old, new = dict(reference.leaves(old)), dict(reference.leaves(new))
+    for name, r in reference.leaves(ref):
         dn, dr, dis = (np.asarray(a, np.float64)
-                       for a in _update_counts(old[name], new[name],
-                                               ref[name]))
+                       for a in _update_counts(old[name], new[name], r))
         dr = np.maximum(dr, 1.0)
         flip = max(flip, float(np.max(np.abs(dn - dr) / dr)))
         mismatch = max(mismatch, float(np.max(dis / (2.0 * dr))))
@@ -295,7 +306,7 @@ def reference_readings(model, opt, seed, rows, seq, quant, keep=None,
 
 def _checks(model, tr, readings, ref, seed):
     lim = tr["check"]
-    old = W.make(model, model["param_dtype"], seed)[1]["blocks"]
+    old = W.make(model, model["param_dtype"], seed)[1]
     loss_gap, g_gap, c_gap = gaps(*readings[:3], ref[:3])
     flip, mismatch = update_gaps(old, readings[3], ref[3])
     log(f"[check] losses program {readings[0]} reference {ref[0]}")

@@ -1,24 +1,36 @@
 """Weights and SRigL masks made by the benchmark from ``--seed``.
 
 Everything here is made on the device in one jitted call, in the layout the
-program's dense transformer takes (``params["blocks"][<name>]`` stacked over
-layers, ``masks["blocks"][<stack>]``), and in the storage type the cell
-states. The plain reference reads the same arrays, so the two are compared
+configuration's architecture module gives (``harness.archs``: every leaf's
+path, leading dims and kind) and in the storage type the cell states. A
+sparse matrix gets a mask of constant fan-in, one per index of its leading
+dims: ``(L,)`` for a stack of layers, ``(L, E)`` for a stack of expert
+layers. The plain reference reads the same arrays, so the two are compared
 on one set of weights that neither made.
 """
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from harness import archs
 
 # the program's RMSNorm multiplies by (1 + scale); the reference by its
 # published weight, so it reads 1 + the stored scale
 NORM_SCALE_STD = 0.1
 EMBED_STD = 0.02
+
+
+class Leaf(NamedTuple):
+    lead: tuple[int, ...]       # leading (stack) dims: (L,), (L, E) or ()
+    shape: tuple[int, ...]      # one vector's or one matrix's (d_in, d_out)
+    kind: str                   # "embed", "norm", "dense" or "sparse"
+    fan_in: int = 0             # a sparse matrix's constant fan-in
 
 
 def key_from_seed(seed: int) -> jax.Array:
@@ -34,22 +46,14 @@ def key_from_seed(seed: int) -> jax.Array:
             return key
 
 
-def layout(model: dict) -> dict[str, tuple[tuple[int, ...], str]]:
-    """Leaf name -> (shape, kind) of the block stack, kind one of
-    "norm", "dense", "sparse"."""
-    n, d, hd = (model["num_hidden_layers"], model["hidden_size"],
-                model["head_dim"])
-    qd = model["num_attention_heads"] * hd
-    kvd = model["num_key_value_heads"] * hd
-    ff = model["intermediate_size"]
-    return {
-        "ln1": ((n, d), "norm"), "ln2": ((n, d), "norm"),
-        "q_norm": ((n, hd), "norm"), "k_norm": ((n, hd), "norm"),
-        "wq": ((n, d, qd), "dense"), "wk": ((n, d, kvd), "dense"),
-        "wv": ((n, d, kvd), "dense"), "wo": ((n, qd, d), "sparse"),
-        "w_gate": ((n, d, ff), "sparse"), "w_up": ((n, d, ff), "sparse"),
-        "w_down": ((n, ff, d), "sparse"),
-    }
+def layout(model: dict) -> dict[tuple[str, ...], Leaf]:
+    return archs.of(model).layout(model)
+
+
+def fan_ins(model: dict) -> dict[tuple[str, ...], int]:
+    """Mask path -> constant fan-in, for every sparse matrix."""
+    return {path: leaf.fan_in for path, leaf in layout(model).items()
+            if leaf.kind == "sparse"}
 
 
 def _constant_fan_in(key, d_in: int, d_out: int, k: int) -> jax.Array:
@@ -60,41 +64,51 @@ def _constant_fan_in(key, d_in: int, d_out: int, k: int) -> jax.Array:
     return jnp.zeros((d_out, d_in), bool).at[rows, idx].set(True).T
 
 
-def _per_layer(fn, key, n: int):
-    """Stack ``fn(key_i)`` over n layers one layer at a time (lax.map), so
-    temporaries stay at one layer's size."""
-    return jax.lax.map(fn, jax.random.split(key, n))
+def _per_matrix(fn, key, lead):
+    """``fn(key_i)`` for each index of the leading dims, one at a time
+    (lax.map), so temporaries stay at one matrix's size."""
+    out = jax.lax.map(fn, jax.random.split(key, math.prod(lead)))
+    return out.reshape(*lead, *out.shape[1:])
+
+
+def _n_keys(leaf: Leaf) -> int:
+    """A stacked leaf draws a values key and a masks key whatever its kind,
+    an unstacked one a values key (and a masks key where sparse): the order
+    in which the first layout drew them, kept so that a seed's weights stay
+    what they were."""
+    return 2 if leaf.lead or leaf.kind == "sparse" else 1
+
+
+def _set(tree: dict, path: tuple, leaf) -> None:
+    for p in path[:-1]:
+        tree = tree.setdefault(p, {})
+    tree[path[-1]] = leaf
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def _make(model_items: tuple, dtype: str, key):
-    model = unfreeze(model_items)
+def _make(leaves: tuple, dtype: str, key):
     dt = jnp.dtype(dtype)
-    fan = model["sparsity"]["fan_in"]
-    vocab, d = model["vocab_size"], model["hidden_size"]
-    keys = iter(jax.random.split(key, 64))
-    params = {"embed": (jax.random.normal(next(keys), (vocab, d))
-                        * EMBED_STD).astype(dt),
-              "final_norm": (jax.random.normal(next(keys), (d,))
-                             * NORM_SCALE_STD).astype(dt)}
-    blocks, masks = {}, {}
-    for name, (shape, kind) in layout(model).items():
-        k_val, k_mask = next(keys), next(keys)
-        n = shape[0]
-        if kind == "norm":
-            blocks[name] = (jax.random.normal(k_val, shape)
-                            * NORM_SCALE_STD).astype(dt)
+    keys = iter(jax.random.split(key, sum(_n_keys(l) for _, l in leaves)))
+    params, masks = {}, {}
+    for path, leaf in leaves:
+        k_val = next(keys)
+        k_mask = next(keys) if _n_keys(leaf) == 2 else None
+        if leaf.kind in ("embed", "norm"):
+            std = EMBED_STD if leaf.kind == "embed" else NORM_SCALE_STD
+            _set(params, path, (jax.random.normal(
+                k_val, leaf.lead + leaf.shape) * std).astype(dt))
             continue
-        std = 1.0 / np.sqrt(fan[name] if kind == "sparse" else shape[1])
-        blocks[name] = _per_layer(
-            lambda k: (jax.random.normal(k, shape[1:]) * std).astype(dt),
-            k_val, n)
-        if kind == "sparse":
-            masks[name] = _per_layer(
-                lambda k: _constant_fan_in(k, shape[1], shape[2],
-                                           int(fan[name])), k_mask, n)
-    params["blocks"] = blocks
-    return params, {"blocks": masks}
+        d_in, d_out = leaf.shape
+        std = 1.0 / np.sqrt(leaf.fan_in if leaf.kind == "sparse" else d_in)
+        value = lambda k: (jax.random.normal(k, leaf.shape) * std).astype(dt)
+        mask = lambda k: _constant_fan_in(k, d_in, d_out, leaf.fan_in)
+        if leaf.lead:
+            value = functools.partial(_per_matrix, value, lead=leaf.lead)
+            mask = functools.partial(_per_matrix, mask, lead=leaf.lead)
+        _set(params, path, value(k_val))
+        if leaf.kind == "sparse":
+            _set(masks, path, mask(k_mask))
+    return params, masks
 
 
 def freeze(obj):
@@ -114,16 +128,6 @@ def unfreeze(obj):
     return obj
 
 
-def model_keys(model: dict) -> dict:
-    """The parts of a configuration that shape the weights."""
-    keep = ("num_hidden_layers", "hidden_size", "head_dim",
-            "num_attention_heads", "num_key_value_heads", "intermediate_size",
-            "vocab_size")
-    out = {k: model[k] for k in keep}
-    out["sparsity"] = {"fan_in": dict(model["sparsity"]["fan_in"])}
-    return out
-
-
 def make(model: dict, dtype: str, seed: int):
     """(params, masks) on the default device, stored as ``dtype``."""
-    return _make(freeze(model_keys(model)), dtype, key_from_seed(seed))
+    return _make(tuple(layout(model).items()), dtype, key_from_seed(seed))

@@ -1,6 +1,6 @@
 """Forward and backward operations the window's training steps require
-(harness.cost.train_flops_per_step, sparse stacks at 2 x nnz) per second of
-the window, over the chip's bf16 peak, in %."""
+(the architecture module's ``flops_per_step``, sparse stacks at 2 x nnz)
+per second of the window, over the chip's bf16 peak, in %."""
 
 
 def read(out):

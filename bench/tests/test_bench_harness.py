@@ -1,15 +1,20 @@
 """The harness end to end on the CPU at the files' rehearsal sizes: each
-cell's run, the refusal to run without a TPU, a cell and a metric added as
-data files alone, the controls, and the faults the check must catch."""
+cell's run, the refusal to run without a TPU, a cell, a metric and an
+architecture added as new files alone, the controls, and the faults the
+check must catch."""
+import importlib.util
 import json
 import os
+import pathlib
 import shutil
 import subprocess
 import sys
 
+import cpu_trace
 import pytest
 
 from harness import core
+from harness import trace as TR
 
 CELLS = ["train-dst"]
 
@@ -35,6 +40,11 @@ def test_rehearsal_runs_each_cell(cell, tmp_path):
     assert out["device"]["platform"] == "cpu"
     # a CPU run prints counts, never a device metric
     assert set(out["metrics"]) == {"rehearsal_counts"}
+    # the Trainer's counters are handed on: it waits on the step counter
+    # and on whether the DST update is due, once each a step
+    counts = out["metrics"]["rehearsal_counts"]
+    assert counts["program.host_syncs"] >= 2 * counts["steps"] > 0
+    assert counts["program.straggler_events"] >= 0
     assert list(out)[-1] == "checks"
     assert "programs compiled in the window: 0" in err
 
@@ -58,9 +68,63 @@ def test_without_the_program_a_run_fails(tmp_path):
     assert rc != 0 and last == ""
 
 
-def test_a_cell_and_a_metric_added_as_data_alone(tmp_path):
+def _add_cell(root, spec, name, config, traffic, reader):
+    """A traffic file, a reader of the cell's per-layer metric, and their
+    entries in BENCHMARK.json."""
+    (root / "bench" / "traffic" / f"{name}.json").write_text(
+        json.dumps(traffic))
+    (root / "bench" / "metrics" / f"{name}_metric.py").write_text(reader)
+    spec["workloads"].append({"name": f"train-{name}", "config": config,
+                              "traffic": name, "chips": 1,
+                              "why": "added as data alone"})
+    spec["per_layer"].append({"name": f"{name}_metric", "unit": "ms",
+                              "better": "lower", "source": "device_trace",
+                              "layer": "training step",
+                              "moves": "train_tok_s",
+                              "workloads": [f"train-{name}"]})
+
+
+def _add_architecture(root, spec, model):
+    """An architecture module and a configuration that names it."""
+    shutil.copy(pathlib.Path(__file__).parent / "second_arch.py",
+                root / "bench" / "harness" / "archs" / "qwen3_untied.py")
+    model = dict(model, name="qwen3-untied", model_type="qwen3_untied",
+                 tie_word_embeddings=False)
+    (root / "bench" / "configs" / "qwen3-untied.json").write_text(
+        json.dumps(model))
+    spec["configs"].append({"name": "qwen3-untied",
+                            "source": "https://huggingface.co/Qwen/Qwen3-1.7B",
+                            "file": "bench/configs/qwen3-untied.json",
+                            "reduced": ["num_hidden_layers"],
+                            "why": "no qk-norm, untied head"})
+
+
+def _untied_reads(root, spec, monkeypatch, tmp_path):
+    """The added reader on a CPU-traced run of the added cell, in this
+    process, with the copy's architecture module as the harness finds it."""
+    name = "harness.archs.qwen3_untied"
+    mod_spec = importlib.util.spec_from_file_location(
+        name, root / "bench" / "harness" / "archs" / "qwen3_untied.py")
+    mod = importlib.util.module_from_spec(mod_spec)
+    monkeypatch.setitem(sys.modules, name, mod)
+    mod_spec.loader.exec_module(mod)
+    _, model, traffic = core.resolve(spec, "train-untied", root=root,
+                                     rehearse=True)
+    devices, modules, _, _ = cpu_trace.traced_fit(model, traffic, 5,
+                                                  str(tmp_path / "trace"))
+    trace = TR.from_events([sum(devices, [])], [sum(modules, [])], [])
+    out = core.Outcome(metrics={}, checks=[], attempted=0, failed=0,
+                       trace=trace, model=model, traffic=traffic)
+    from harness import cli
+    return cli.per_layer(spec, "train-untied", out, root=root)
+
+
+@pytest.mark.parametrize("added", ["cell", "architecture"])
+def test_a_cell_and_a_metric_added_as_data_alone(added, monkeypatch,
+                                                 tmp_path):
     """A later change adds a traffic file, a reader and entries in
-    BENCHMARK.json; no file the harness had is edited."""
+    BENCHMARK.json, or besides them an architecture module and its
+    configuration; no file the harness had is edited."""
     root = tmp_path / "repo"
     root.mkdir()
     shutil.copytree(core.BENCH, root / "bench",
@@ -70,30 +134,41 @@ def test_a_cell_and_a_metric_added_as_data_alone(tmp_path):
               if p.is_file()}
     spec = json.loads((core.ROOT / "BENCHMARK.json").read_text())
     dst = json.loads((core.BENCH / "traffic" / "dst.json").read_text())
-    dst["rehearsal"]["batch"] = 3
-    (root / "bench" / "traffic" / "dst3.json").write_text(json.dumps(dst))
-    (root / "bench" / "metrics" / "window_steps.py").write_text(
-        "def read(out):\n    return float(out.attempted)\n")
-    spec["workloads"].append({"name": "train-dst3", "config":
-                              "qwen3-1.7b-train-5l", "traffic": "dst3",
-                              "chips": 1, "why": "three rows a step"})
-    spec["per_layer"].append({"name": "window_steps", "unit": "steps",
-                              "better": "higher", "source": "host_clock",
-                              "layer": "training step",
-                              "moves": "train_tok_s",
-                              "workloads": ["train-dst3"]})
+    if added == "cell":
+        dst["rehearsal"]["batch"] = 3
+        _add_cell(root, spec, "dst3", "qwen3-1.7b-train-5l", dst,
+                  "def read(out):\n    return float(out.attempted)\n")
+        workload = "train-dst3"
+    else:
+        model = json.loads((core.BENCH / "configs"
+                            / "qwen3-1.7b-train-5l.json").read_text())
+        _add_architecture(root, spec, model)
+        _add_cell(root, spec, "untied", "qwen3-untied", dst,
+                  "from harness import scopes\n\n\ndef read(out):\n"
+                  "    return scopes.ms_per_execution(out, 'train_step', "
+                  "'attention')\n")
+        workload = "train-untied"
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
-    rc, last, err = _run(["--workload", "train-dst3", "--seed", "7",
-                          "--seconds", "1", "--rehearse"], root=root,
-                         cache=tmp_path / "cache")
+    args = ["--workload", workload, "--seed", "7", "--seconds", "1",
+            "--rehearse"]
+    rc, last, err = _run(args, root=root, cache=tmp_path / "cache")
     assert rc == 0, err[-3000:]
-    assert json.loads(last)["correct"] is True
+    assert json.loads(last)["correct"] is True, err[-3000:]
+    if added == "architecture":
+        rc, last, err = _run(args + ["--fault", "half"], root=root,
+                             cache=tmp_path / "cache")
+        assert rc == 0, err[-3000:]
+        assert json.loads(last)["correct"] is False, err[-3000:]
+        got = _untied_reads(root, spec, monkeypatch, tmp_path)
+        assert got["untied_metric"]["value"] > 0
+        assert got["untied_metric"]["unit"] == "ms"
+    else:
+        from harness import cli
+        out = core.Outcome(metrics={}, checks=[], attempted=4, failed=0)
+        got = cli.per_layer(spec, workload, out, root=root)
+        assert got["dst3_metric"] == {"value": 4.0, "unit": "ms"}
     after = {p: p.read_bytes() for p in before}
     assert after == before
-    from harness import cli
-    out = core.Outcome(metrics={}, checks=[], attempted=4, failed=0)
-    got = cli.per_layer(spec, "train-dst3", out, root=root)
-    assert got["window_steps"] == {"value": 4.0, "unit": "steps"}
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -1,13 +1,20 @@
-"""Trace reduction, operation counts, the peak table, and the reference's
-topology update."""
+"""Trace reduction, operation counts, the peak table, the weights made
+from the seed, and the reference's topology update and its comparison, also
+over stacks of expert matrices."""
+import hashlib
 import json
+import sys
+import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from harness import core, cost, peaks, reference
+from harness import core, cost, peaks, reference, train
 from harness import trace as TR
+from harness import weights as W
+from harness.archs import qwen3
 
 TRAIN = json.loads((core.BENCH / "configs" / "qwen3-1.7b-train-5l.json")
                    .read_text())
@@ -73,7 +80,7 @@ def test_counts_at_qwen3_sizes():
     lin = (2 * cost.sparse_nnz(TRAIN) + 2 * 5 * 2048 * (2048 + 1024 + 1024)
            + 2 * 2048 * 151936)
     att = 4 * 5 * 16 * 128 * 2048 * 2049 / 2
-    assert cost.train_flops_per_step(TRAIN, 1, 2048) == pytest.approx(
+    assert qwen3.flops_per_step(TRAIN, 1, 2048, {}) == pytest.approx(
         3 * (2048 * lin + att))
 
 
@@ -131,3 +138,112 @@ def test_drop_fraction_follows_the_cosine_schedule():
     assert reference.drop_fraction(sp, 0) == pytest.approx(0.3)
     assert reference.drop_fraction(sp, 37_500) == pytest.approx(0.15)
     assert reference.drop_fraction(sp, 75_000) == 0.0
+
+
+# -- weights from the seed ----------------------------------------------------
+
+
+def _digest(tree) -> str:
+    h = hashlib.sha256()
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    for path, leaf in sorted(flat, key=lambda kv: jax.tree_util.keystr(kv[0])):
+        a = np.asarray(leaf)
+        h.update(f"{jax.tree_util.keystr(path)} {a.dtype} {a.shape}".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+def test_qwen3_weights_and_masks_stay_what_the_seed_gave():
+    """Weights and masks at the rehearsal size for one seed, bit for bit as
+    the benchmark's first layout made them (its digest, taken then)."""
+    _, model, _ = core.resolve(core.load_spec(), "train-dst", rehearse=True)
+    params, masks = W.make(model, model["param_dtype"], 2**31 + 5)
+    assert _digest((params, masks)) == (
+        "1efbc3b8e1bb4b8b6a4e116f5b1ef3a38bd78364e1ce64f01b5a455185536407")
+
+
+# -- stacks of expert matrices: leading dims (L, E) --------------------------
+
+LEAD, D_IN, D_OUT, K = (2, 3), 16, 12, 4
+
+
+@pytest.fixture
+def experts(monkeypatch):
+    """A configuration whose architecture has one sparse stack of expert
+    matrices, (2 layers, 3 experts, 16, 12) at fan-in 4, and its weights,
+    masks and a gradient."""
+    arch = types.ModuleType("harness.archs.experts_only")
+    arch.layout = lambda model: {
+        ("blocks", "experts", "w_up"): W.Leaf(LEAD, (D_IN, D_OUT), "sparse",
+                                              K)}
+    monkeypatch.setitem(sys.modules, arch.__name__, arch)
+    model = {"model_type": "experts_only",
+             "sparsity": dict(TRAIN["sparsity"], fan_in={})}
+    params, masks = W.make(model, "float32", 3)
+    grads = jax.tree.map(
+        lambda p: jax.random.normal(jax.random.PRNGKey(4), p.shape), params)
+    return model, params, masks, grads
+
+
+def _stack(tree):
+    return tree["blocks"]["experts"]["w_up"]
+
+
+def test_masks_over_expert_dims_have_constant_fan_in(experts):
+    model, params, masks, _ = experts
+    m = _stack(masks)
+    assert m.shape == (*LEAD, D_IN, D_OUT) and m.dtype == jnp.bool_
+    assert (jnp.sum(m, axis=-2) == K).all()
+    # each matrix drew its own mask
+    assert int((m[0, 0] ^ m[1, 2]).sum()) > 0
+    assert train.fan_in_faults(model, masks, jax.tree.map(
+        lambda a: jnp.ones(a.shape[:-2] + a.shape[-1:], bool), masks)) == 0
+
+
+def test_dst_masks_update_each_expert_matrix_alone(experts):
+    model, params, masks, grads = experts
+    step = 100
+    new = _stack(reference.dst_masks(model, params, grads, masks, step))
+    w, g, m = _stack(params), _stack(grads), _stack(masks)
+    drop = reference.drop_fraction(model["sparsity"], step)
+    sp = model["sparsity"]
+    for i in range(LEAD[0]):
+        for e in range(LEAD[1]):
+            want, _ = reference.srigl_layer(
+                w[i, e], g[i, e], m[i, e], jnp.ones(D_OUT, bool),
+                int(drop * int(m[i, e].sum())), K, float(sp["gamma_sal"]),
+                bool(sp["ablation"]))
+            assert (new[i, e] == want).all(), (i, e)
+    assert not (new == m).all()
+
+
+def test_update_gaps_catch_one_flip_in_one_expert(experts):
+    """One connection dropped in one expert of one layer: that matrix's
+    count of drops is off by one, over its own count, not the stack's."""
+    model, params, masks, grads = experts
+    ref = reference.dst_masks(model, params, grads, masks, 100)
+    assert train.update_gaps(masks, ref, ref) == (0.0, 0.0)
+    r = _stack(ref)
+    kept = jnp.argwhere(r[1, 2] & _stack(masks)[1, 2])[0]
+    flipped = r.at[1, 2, kept[0], kept[1]].set(False)
+    new = {"blocks": {"experts": {"w_up": flipped}}}
+    dropped = int((_stack(masks)[1, 2] & ~r[1, 2]).sum())
+    flip, mismatch = train.update_gaps(masks, new, ref)
+    assert flip == pytest.approx(1 / dropped)
+    assert mismatch == pytest.approx(1 / (2 * dropped))
+
+
+def test_fan_in_faults_are_counted_per_matrix(experts):
+    model, _, masks, _ = experts
+    m = _stack(masks)
+    active = jnp.ones((*LEAD, D_OUT), bool)
+    tree = lambda x: {"blocks": {"experts": {"w_up": x}}}
+    # one more input to a neuron of two matrices
+    broken = m.at[0, 1, :, 0].set(True).at[1, 0, :, 5].set(True)
+    assert train.fan_in_faults(model, tree(broken), tree(active)) == 2
+    # an ablated neuron that keeps its inputs, in a third matrix
+    off = active.at[1, 1, 3].set(False)
+    assert train.fan_in_faults(model, tree(broken), tree(off)) == 3
+    # ablated with no inputs left: sound
+    cleared = m.at[1, 1, :, 3].set(False)
+    assert train.fan_in_faults(model, tree(cleared), tree(off)) == 0

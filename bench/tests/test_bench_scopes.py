@@ -1,52 +1,16 @@
 """Attribution of device time to the program's named scopes, and the host
 spans of ``Trainer.fit`` on the device trace's clock: a training run traced
 with the CPU profiler, where each executor thread plays a device."""
-import glob
-import os
-
-import jax
-import jax.numpy as jnp
+import cpu_trace
 import pytest
 
-from harness import core, program, scopes, train
+from harness import core, scopes
 from harness import trace as TR
-from harness import weights as W
 
 READERS = ["sparse_stacks_ms", "attention_ms", "head_ms", "layer_scan_ms",
            "optimizer_ms", "recompute_ms", "dst_grad_ms", "dst_select_ms"]
-
-
-def _cpu_trace(trace_dir):
-    """(devices, modules, spans, runs) of a CPU profile: each executor
-    thread's operations (events with an ``hlo_op`` stat), each program run
-    on that thread as a module execution (from its first operation to its
-    last), every ``trainer.*`` span, and the start of each program run by
-    (module, run id)."""
-    from jax.profiler import ProfileData
-    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
-                                   "*.xplane.pb"))
-    devices, modules, spans, runs = [], [], [], {}
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:CPU"):
-            continue
-        for line in plane.lines:
-            ops, mods = [], {}
-            for e in line.events:
-                s, t = int(e.start_ns), int(e.start_ns + e.duration_ns)
-                if e.name.startswith("trainer."):
-                    spans.append((e.name, s, t))
-                stats = dict(e.stats)
-                if "hlo_op" not in stats:
-                    continue
-                ops.append((e.name, s, t))
-                key = (stats["hlo_module"], stats.get("run_id"))
-                m = mods.get(key, (key[0], s, t))
-                mods[key] = (key[0], min(m[1], s), max(m[2], t))
-                runs[key] = min(runs.get(key, s), s)
-            if ops:
-                devices.append(ops)
-                modules.append(list(mods.values()))
-    return devices, modules, spans, runs
+_, MODEL, _ = core.resolve(core.load_spec(), "train-dst", rehearse=True)
+NAMES = scopes.names(MODEL)
 
 
 @pytest.fixture(scope="module")
@@ -54,39 +18,21 @@ def traced_run(tmp_path_factory):
     """Two ``fit`` steps, the second ending in the DST update, at the
     rehearsal size under the CPU profiler: the trace, the op_name of each
     instruction of the two programs compiled again, and their text."""
-    from repro.train.trainer import Trainer
     _, model, traffic = core.resolve(core.load_spec(), "train-dst",
                                      rehearse=True)
-    rows, seq = traffic["batch"], traffic["seq_len"]
-    cfg = program.arch_config(model, dtype=model["compute_dtype"],
-                              param_dtype=model["param_dtype"])
-    params, masks = W.make(model, model["param_dtype"], 11)
-    reg = program.check_layout(cfg, model, params)
-    delta_t = int(model["sparsity"]["delta_t"])
-    state = train._state(cfg, reg, params, masks, delta_t - 3,
-                         jax.random.PRNGKey(3))
-    lr = float(model["optimizer"]["lr"])
-    trainer = Trainer(cfg=cfg, lr_fn=lambda s: jnp.float32(lr), log_every=1)
-    feed = train.Feed(11, rows, seq, model["vocab_size"])
-    quiet = lambda msg: None
-    state = trainer.fit(state, feed, 2, log_fn=quiet)    # compiles both
-    d = str(tmp_path_factory.mktemp("trace"))
-    jax.profiler.start_trace(d)
-    state = trainer.fit(state, feed, 2, log_fn=quiet)
-    jax.block_until_ready(state.params)
-    jax.profiler.stop_trace()
+    got = cpu_trace.traced_fit(model, traffic, 11,
+                               str(tmp_path_factory.mktemp("trace")))
     texts = scopes.compiled_texts(model, traffic)
-    return (_cpu_trace(d), {p: scopes.op_paths(x) for p, x in texts.items()},
-            texts)
+    return (got, {p: scopes.op_paths(x) for p, x in texts.items()}, texts)
 
 
 def test_every_scope_is_attributed_and_the_parts_add_up(traced_run):
     (devices, modules, _, _), paths, _ = traced_run
     lo = min(e[1] for d in devices for e in d)
     hi = max(e[2] for d in devices for e in d)
-    seconds = {p: {} for p in scopes.SCOPES}
+    seconds = {p: {} for p in NAMES}
     for ops, mods in zip(devices, modules):
-        got = scopes.attribute(ops, mods, lo, hi, paths)
+        got = scopes.attribute(ops, mods, lo, hi, paths, NAMES)
         for p, r in got.items():
             # the program that ran is the program compiled again
             assert r["unmatched"] == 0.0
@@ -99,12 +45,12 @@ def test_every_scope_is_attributed_and_the_parts_add_up(traced_run):
             for k, v in r["seconds"].items():
                 seconds[p][k] = seconds[p].get(k, 0.0) + v
             seconds[p]["remat"] = seconds[p].get("remat", 0.0) + r["remat"]
-    for p, names in scopes.SCOPES.items():
+    for p, names in NAMES.items():
         for name in (*names, "remat"):
             assert seconds[p].get(name, 0.0) > 0, (p, name, seconds[p])
     # the model's scopes inside the DST gradient count as it
-    assert set(seconds["dst_step"]) <= {*scopes.SCOPES["dst_step"],
-                                        "other", "remat"}
+    assert set(seconds["dst_step"]) <= {*NAMES["dst_step"], "other",
+                                        "remat"}
 
 
 def test_trainer_spans_nest_in_their_step_on_the_device_clock(traced_run):
@@ -143,13 +89,13 @@ def test_trainer_spans_nest_in_their_step_on_the_device_clock(traced_run):
     ("", "other"),
 ])
 def test_scope_of_a_train_step_path(path, scope):
-    assert scopes.scope_of(path, scopes.SCOPES["train_step"]) == scope
+    assert scopes.scope_of(path, NAMES["train_step"]) == scope
 
 
 def test_a_dst_path_counts_as_its_own_scope():
     path = ("jit(dst_step)/dst_grad/transpose(jvp(blocks))/while/body/"
             "checkpoint/sparse/dot_general")
-    assert scopes.scope_of(path, scopes.SCOPES["dst_step"]) == "dst_grad"
+    assert scopes.scope_of(path, NAMES["dst_step"]) == "dst_grad"
 
 
 def test_attribution_of_events_by_module_execution():
@@ -166,7 +112,7 @@ def test_attribution_of_events_by_module_execution():
     ops = [("while.9", 0, 60), ("fusion.1", 10, 40), ("fusion.2", 60, 90),
            ("copy.3", 90, 95), ("fusion.1", 110, 140), ("fusion.4", 210, 220),
            ("fusion.1", 160, 170)]           # in no program execution
-    got = scopes.attribute(ops, modules, 0, 1000, paths)
+    got = scopes.attribute(ops, modules, 0, 1000, paths, NAMES)
     tr, dst = got["train_step"], got["dst_step"]
     assert tr["executions"] == 2 and dst["executions"] == 1
     assert tr["module_s"] == pytest.approx(200e-9)
@@ -270,7 +216,7 @@ def test_compiled_instructions_land_in_their_scope(traced_run, program,
         if ran & opcodes:
             found += 1
             path, rule = paths[program][inst]
-            assert scopes.scope_of(path, scopes.SCOPES[program]) in allowed, (
+            assert scopes.scope_of(path, NAMES[program]) in allowed, (
                 inst, path, rule)
     assert found
 
@@ -286,11 +232,57 @@ def test_each_reader_gives_nothing_without_a_trace(name):
 
 
 def test_the_readers_are_the_benchmarks_metrics():
+    """Each reader is a per-layer metric of the benchmark that moves
+    ``train_tok_s``, and every cell it lists exists and reports it."""
     spec = core.load_spec()
+    cells = {w["name"] for w in spec["workloads"]}
     entries = {m["name"]: m for m in spec["per_layer"]}
-    for name in READERS:
-        assert entries[name]["workloads"] == ["train-dst"]
+    tok_s, = (m for m in spec["end_to_end"] if m["name"] == "train_tok_s")
+    reporting = set(tok_s.get("workloads", cells)) & cells
+    for name in READERS + ["host_syncs_per_step"]:
         assert entries[name]["moves"] == "train_tok_s"
+        assert entries[name]["workloads"]
+        assert set(entries[name]["workloads"]) <= reporting, name
+
+
+def test_host_syncs_per_step_reads_the_programs_counter():
+    reader = core.load_reader("host_syncs_per_step")
+    out = lambda **c: core.Outcome(metrics={}, checks=[], attempted=0,
+                                   failed=0, counters=c)
+    assert reader.read(out(steps=74)) is None
+    assert reader.read(out(steps=0, **{"program.host_syncs": 3})) is None
+    assert reader.read(out(steps=74, **{"program.host_syncs": 150})) == \
+        pytest.approx(150 / 74)
+
+
+def test_op_paths_reads_an_instructions_continuation_lines():
+    """A Pallas call prints its kernel_metadata over three lines and its
+    op_name on the third, as do the get-tuple-elements of its results; the
+    line after them is an instruction of its own."""
+    text = r"""ENTRY %main (x: bf16[256,128]) -> bf16[256,128] {
+  %x = bf16[256,128]{1,0} parameter(0)
+  %iota.1 = s32[256,128]{1,0} iota(), iota_dimension=1, metadata={op_name="jit(train_step)/jvp(blocks)/while/body/iota"}
+  %splash_mha_dq_no_residuals.1 = (f32[256,128]{1,0:T(8,128)}, bf16[2,256,128]{2,1,0:T(8,128)(2,1)}) custom-call(%x, %iota.1), custom_call_target="tpu_custom_call", operand_layout_constraints={bf16[256,128]{1,0}, s32[256,128]{1,0}}, frontend_attributes={kernel_metadata={
+"xprof_metadata":"{"block_q_dq": 256, "block_kv_dq": 256, "q_layout": 1}"
+}}, metadata={op_name="jit(train_step)/transpose(jvp(blocks))/while/body/attention/splash_mha_dq_no_residuals/pallas_call" stack_frame_id=15}, backend_config={"custom_call_config":{"body":"TUzvUgFN"}}
+  %pallas_call.4 = bf16[2,256,128]{2,1,0:T(8,128)(2,1)} get-tuple-element(%splash_mha_dq_no_residuals.1), index=1, frontend_attributes={kernel_metadata={
+"xprof_metadata":"{"block_q_dq": 256, "block_kv_dq": 256, "q_layout": 1}"
+}}, metadata={op_name="jit(train_step)/transpose(jvp(blocks))/while/body/attention/splash_mha_dq_no_residuals/pallas_call" stack_frame_id=15}
+  ROOT %copy.2 = bf16[256,128]{1,0} copy(%x), metadata={op_name="jit(train_step)/transpose(jvp(blocks))/while/body/copy"}
+}
+"""
+    paths = scopes.op_paths(text)
+    kernel = ("jit(train_step)/transpose(jvp(blocks))/while/body/attention/"
+              "splash_mha_dq_no_residuals/pallas_call", "own")
+    assert paths["splash_mha_dq_no_residuals.1"] == kernel
+    assert paths["pallas_call.4"] == kernel
+    assert scopes.scope_of(kernel[0], NAMES["train_step"]) == "attention"
+    assert paths["copy.2"] == ("jit(train_step)/transpose(jvp(blocks))/"
+                               "while/body/copy", "own")
+    assert paths["x"] == ("", "none")
+    assert [i for _, i, _ in scopes.instructions(text)] == [
+        "x", "iota.1", "splash_mha_dq_no_residuals.1", "pallas_call.4",
+        "copy.2"]
 
 
 def test_busy_time_and_the_existing_readers_are_unchanged():
