@@ -371,6 +371,20 @@ def instruction_shapes(hlo_text: str, op: str = "gather") -> list[tuple[int, ...
     return out
 
 
+def matmuls_spanning(hlo_text: str, size: int) -> list[Instruction]:
+    """Every ``dot`` and ``convolution`` in the module, fusion bodies
+    included, whose result or one of whose operands has a dimension of
+    ``size``. Each one inside a loop body is one pass over the loop's
+    trips, so with ``size`` the vocabulary this counts a program's passes
+    over its LM head."""
+    comps = parse_hlo(hlo_text)
+    types = {i.name: i.type_str for c in comps.values() for i in c.instructions}
+    return [i for c in comps.values() for i in c.instructions
+            if i.op in ("dot", "convolution")
+            and any(size in (_dims(t) or ())
+                    for t in [i.type_str] + [types.get(o, "") for o in i.operand_names])]
+
+
 def tp_gathers_shard_local(shapes, nloc: int, k: int, d_out: int) -> bool:
     """The serving SPMD invariant on a tensor-parallel program's gather
     shapes (``instruction_shapes``): the condensed gather runs shard-local

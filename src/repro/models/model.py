@@ -127,11 +127,13 @@ def _any_tp(cfg) -> bool:
     return kinds or (cfg.is_moe and cfg.n_experts % tp == 0)
 
 
-def vocab_hint(cfg, head: jax.Array) -> jax.Array:
-    """Shard the LM head's vocab dim over 'model' (TP archs only)."""
+def vocab_hint(cfg, head: jax.Array, axis: int = -1) -> jax.Array:
+    """Shard the LM head's vocab dim (``axis``) over 'model' (TP archs only)."""
     if not _any_tp(cfg):
         return head
-    return shard_hint(head, *([None] * (head.ndim - 1) + ["model"]))
+    spec = [None] * head.ndim
+    spec[axis] = "model"
+    return shard_hint(head, *spec)
 
 
 def seq_shard(cfg, x: jax.Array) -> jax.Array:
@@ -587,11 +589,15 @@ def embed_inputs(cfg, params: Params, batch: dict) -> tuple[jax.Array, Any]:
 def cross_entropy_chunked(hidden: jax.Array, lm_head: jax.Array,
                           targets: jax.Array, chunk: int,
                           loss_mask: jax.Array | None = None,
-                          valid_vocab: int = 0, cfg=None) -> jax.Array:
+                          valid_vocab: int = 0, cfg=None, *,
+                          vocab_rows: bool = False) -> jax.Array:
     """Mean token CE without materializing (B, T, V) logits.
 
-    hidden: (B, T, d); lm_head: (d, V); targets: (B, T) int32.
-    Scans over T chunks; each chunk computes (B, Tc, V) f32 logits.
+    hidden: (B, T, d); lm_head: (d, V), or with ``vocab_rows`` a (V, d)
+    table (tied embeddings), contracted over d as it is stored so that
+    neither the table nor its gradient is transposed; targets: (B, T) int32.
+    Scans over T chunks; each chunk computes (B, Tc, V) logits. Under
+    differentiation the same sweep also forms the gradient (``_ce_sweep``).
     """
     b, t, d = hidden.shape
     chunk = min(chunk, t)
@@ -607,34 +613,84 @@ def cross_entropy_chunked(hidden: jax.Array, lm_head: jax.Array,
 
     hc = hidden.reshape(b, nc, chunk, d).transpose(1, 0, 2, 3)
     tc = targets.reshape(b, nc, chunk).transpose(1, 0, 2)
-    mc = lm.reshape(b, nc, chunk).transpose(1, 0, 2)
+    mc = lm.reshape(b, nc, chunk).transpose(1, 0, 2).astype(jnp.float32)
 
     # vocab-shard the head over the TP axis (tied embeddings arrive d-sharded;
     # without this the per-chunk logits would be replicated over 'model' and
     # the partial-sum all-reduce costs chunks x B x Tc x V f32 — the single
     # largest collective in the naive lowering)
-    v_total = lm_head.shape[-1]
+    v_axis = 0 if vocab_rows else 1
+    v_total = lm_head.shape[v_axis]
     if cfg is not None:
-        lm_head = vocab_hint(cfg, lm_head)
+        lm_head = vocab_hint(cfg, lm_head, axis=v_axis)
     n_valid = valid_vocab if valid_vocab else v_total
+    return _ce_sweep(n_valid, vocab_rows, hc, lm_head, tc, mc)
 
-    # remat the chunk body: without it the scan stacks every chunk's (B,Tc,V)
-    # f32 logits as backward residuals — i.e. the full (B,T,V) logits tensor
-    # this function exists to avoid (40 GB/device for a 152k vocab at 4k seq).
-    @jax.checkpoint
+
+def _ce_pass(n_valid: int, vocab_rows: bool, hc, lm_head, tc, mc, *,
+             with_grad: bool):
+    """One scan over the chunks: the mean NLL and, ``with_grad``, its
+    gradient in (hc, lm_head) at unit cotangent.
+
+    The loss is the last op of the forward pass and a scalar, so each
+    chunk's dlogits = (softmax - onehot) * mask / count is known as soon as
+    its logits are: dh and dW come from the same logits, with no chunk
+    kept or recomputed for a backward pass. Matmuls take the head in the
+    compute dtype (cast once) and accumulate in f32; logits are that
+    matmul's output, dlogits the compute dtype, dW sums in f32."""
+    w = lm_head.astype(hc.dtype)
+    v_axis = 0 if vocab_rows else 1
+    cols = jnp.arange(w.shape[v_axis])
+    cnt = jnp.maximum(jnp.sum(mc), 1.0)
+
     def step(carry, xs):
-        tot, cnt = carry
+        tot, dw = carry
         h_i, t_i, m_i = xs
-        logits = (h_i @ lm_head.astype(h_i.dtype)).astype(jnp.float32)
-        if n_valid != v_total:  # mask padded vocab columns
-            logits = jnp.where(jnp.arange(v_total) < n_valid, logits, -1e30)
+        logits = jax.lax.dot_general(
+            h_i, w, (((2,), (1 - v_axis,)), ((), ()))).astype(jnp.float32)
+        if n_valid != cols.shape[0]:  # mask padded vocab columns
+            logits = jnp.where(cols < n_valid, logits, -1e30)
         lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, t_i[..., None], axis=-1)[..., 0]
-        nll = (lse - gold) * m_i
-        return (tot + jnp.sum(nll), cnt + jnp.sum(m_i)), None
+        hit = cols == t_i[..., None]
+        gold = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        tot = tot + jnp.sum((lse - gold) * m_i)
+        if not with_grad:
+            return (tot, dw), None
+        dl = ((jnp.exp(logits - lse[..., None]) - hit)
+              * (m_i / cnt)[..., None]).astype(h_i.dtype)
+        dh = jax.lax.dot_general(dl, w, (((2,), (v_axis,)), ((), ())))
+        # dW in the head's own layout: (V, d) for vocab rows, else (d, V)
+        a, c = (dl, h_i) if vocab_rows else (h_i, dl)
+        dw = dw + jax.lax.dot_general(a, c, (((0, 1), (0, 1)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        return (tot, dw), dh
 
-    (tot, cnt), _ = jax.lax.scan(step, (jnp.zeros(()), jnp.zeros(())), (hc, tc, mc))
-    return tot / jnp.maximum(cnt, 1.0)
+    dw0 = jnp.zeros(w.shape, jnp.float32) if with_grad else None
+    (tot, dw), dh = jax.lax.scan(step, (jnp.zeros(()), dw0), (hc, tc, mc))
+    return tot / cnt, dh, dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _ce_sweep(n_valid, vocab_rows, hc, lm_head, tc, mc):
+    """Mean NLL over chunked (nc, B, Tc, d) hidden states; called without
+    differentiation it runs the loss alone."""
+    return _ce_pass(n_valid, vocab_rows, hc, lm_head, tc, mc,
+                    with_grad=False)[0]
+
+
+def _ce_sweep_fwd(n_valid, vocab_rows, hc, lm_head, tc, mc):
+    loss, dh, dw = _ce_pass(n_valid, vocab_rows, hc, lm_head, tc, mc,
+                            with_grad=True)
+    return loss, (dh, dw.astype(lm_head.dtype))
+
+
+def _ce_sweep_bwd(n_valid, vocab_rows, res, g):
+    # the gradient is already whole; scale it by the incoming cotangent
+    dh, dw = res
+    return ((dh * g).astype(dh.dtype), (dw * g).astype(dw.dtype), None, None)
+
+
+_ce_sweep.defvjp(_ce_sweep_fwd, _ce_sweep_bwd)
 
 
 def loss_fn(cfg, params: Params, masks: Masks, batch: dict) -> tuple[jax.Array, dict]:
@@ -658,10 +714,12 @@ def loss_fn(cfg, params: Params, masks: Masks, batch: dict) -> tuple[jax.Array, 
             ]
             loss = sum(losses) / cfg.n_codebooks
         else:
-            head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+            tied = cfg.tie_embeddings
+            head = params["embed"] if tied else params["lm_head"]
             loss = cross_entropy_chunked(hidden, head, batch["targets"], cfg.ce_chunk,
                                          batch.get("loss_mask"),
-                                         valid_vocab=cfg.vocab_size, cfg=cfg)
+                                         valid_vocab=cfg.vocab_size, cfg=cfg,
+                                         vocab_rows=tied)
 
     total = loss + 0.01 * aux
     return total, {"loss": loss, "aux_loss": aux}

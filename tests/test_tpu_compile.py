@@ -22,7 +22,9 @@ from repro import configs
 from repro.core import distributions as D
 from repro.kernels import condensed_matmul as cm
 from repro.kernels import structured_matmul as sm
+from repro.launch import hlo_analysis as H
 from repro.models import attention as A
+from repro.models import model as M
 from repro.sparse import registry as REG
 
 # qwen3-1.7b's sparse stacks (configs/qwen3_1_7b.py) as (d_in, d_out, k):
@@ -35,6 +37,10 @@ BATCHES = (8, 256)               # decode-specialized variant and tiled grid
 # size): 8 x 2048 tokens, 16 q heads over 8 kv heads of 128
 # (bench/configs/qwen3-1.7b-train-5l.json)
 ATTN_CELL = (8, 2048, 16, 8, 128)
+# the train-dst cell's head as (batch, T, d, vocabulary, chunk): bf16
+# hidden states against qwen3-1.7b's tied f32 (151,936, 2048) table, 512
+# positions a chunk
+HEAD_CELL = (8, 2048, D_MODEL, 151_936, 512)
 
 
 @pytest.fixture(scope="module")
@@ -185,3 +191,30 @@ def test_flash_attention_compiles(chip, phase, shape, dtype):
              "splash_mha_dkv_no_residuals"})
     assert {name for _, name in kernels} == want
     assert all("attention" in path.split("/")[1] for path, _ in kernels)
+
+
+@pytest.mark.parametrize("program,passes", [("train", 3), ("dst", 2)])
+def test_fused_head_compiles(chip, program, passes):
+    """The head's vocabulary matmuls at the cell's shapes: logits, dh and
+    dW in the train step, and no dW where only the hidden state's gradient
+    is taken (the DST program's dense gradient)."""
+    b, t, d, v, chunk = HEAD_CELL
+
+    @jax.named_scope("head")
+    def loss(h, table, targets):
+        return M.cross_entropy_chunked(h, table, targets, chunk,
+                                       valid_vocab=v, vocab_rows=True)
+
+    fn = (jax.value_and_grad(loss, argnums=(0, 1)) if program == "train"
+          else jax.grad(loss))
+    text = jax.jit(fn).lower(
+        _spec(chip, (b, t, d), jnp.bfloat16), _spec(chip, (v, d), jnp.float32),
+        _spec(chip, (b, t), jnp.int32)).compile().as_text()
+    matmuls = H.matmuls_spanning(text, v)
+    assert len(matmuls) == passes, [m.name for m in matmuls]
+    assert all("(head)/" in m.attrs for m in matmuls)
+    assert not [s for s in H.instruction_shapes(text, "scatter")
+                if len(s) >= 3 and s[-1] == v]
+    # the table's gradient is summed in its own (V, d) layout: no f32
+    # transposed copy of it is written
+    assert not re.findall(rf"= f32\[{d},{v}\]", text)
